@@ -95,6 +95,7 @@ def run(shapes=None, reps: int = 2) -> dict:
 
     from repro.core.epilogue import Epilogue
     from repro.kernels import ops
+    from repro.kernels.mode import resolve_interpret
 
     shapes = shapes or SMOKE_SHAPES
     rng = np.random.default_rng(0)
@@ -158,7 +159,8 @@ def run(shapes=None, reps: int = 2) -> dict:
     out = {
         "schema_version": BENCH_KERNELS_SCHEMA,
         "device": jax.default_backend(),
-        "interpret": jax.default_backend() != "tpu",
+        "device_kind": jax.devices()[0].device_kind,
+        "interpret": resolve_interpret(None),
         "reps": reps,
         "shapes": rows,
         "all_new_beat_legacy": all(

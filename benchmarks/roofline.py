@@ -393,24 +393,24 @@ def kernel_cost(m: int, k: int, b: int, quant: str = "msgemm",
 
 
 def kernel_attainable_s(m: int, k: int, b: int, quant: str = "msgemm",
-                        d: int = 3, backend: str | None = None) -> float:
+                        d: int = 3, kind: str | None = None) -> float:
     """Roofline lower bound for one (b,k)x(k,m) call on the current (or
-    named) jax backend's hardware model."""
+    named) device kind's peaks (obs.costs.DEVICES)."""
     from repro.obs import costs
 
     return costs.attainable_s(costs.gemm_cost(m, k, b, quant=quant, d=d),
-                              costs.device(backend))
+                              costs.device(kind))
 
 
 def kernel_fraction(measured_s: float, m: int, k: int, b: int,
                     quant: str = "msgemm", d: int = 3,
-                    backend: str | None = None) -> float:
+                    kind: str | None = None) -> float:
     """attainable / measured for one invocation (1.0 = at the roofline)."""
     from repro.obs import costs
 
     return costs.achieved_fraction(
         measured_s, costs.gemm_cost(m, k, b, quant=quant, d=d),
-        costs.device(backend))
+        costs.device(kind))
 
 
 def kernel_report(bench_path: str | None = None,
@@ -434,7 +434,7 @@ def kernel_report(bench_path: str | None = None,
     except (OSError, ValueError):
         return []
     dev_name = doc.get("device", "cpu")
-    dev = costs.DEVICES.get(dev_name, costs.DEVICES["cpu"])
+    dev = costs.device(doc.get("device_kind", dev_name))
     interpret = bool(doc.get("interpret", dev_name != "tpu"))
     calib = pm.load_calibration(calibration_path, device=dev_name,
                                 interpret=interpret)

@@ -16,6 +16,7 @@ model linear in every architecture routes through here.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 
 from repro import obs
@@ -131,11 +132,15 @@ def execute(params: dict, x, cfg, *, in_dim: int | None = None,
             "dispatch_epilogue_total",
             help="non-identity epilogues by fused/unfused execution",
             fused="true" if fuse else "false").inc()
-    if p.shard is not None and p.shard.is_sharded:
+    sharded = p.shard is not None and p.shard.is_sharded
+    if sharded or not be.partitionable:
         from repro.distributed.sharding import active_mesh
 
         mesh = active_mesh()
         if mesh is not None:
+            if not sharded:  # a kernel XLA cannot partition: replicate it
+                p = dataclasses.replace(p, shard=_shard.ShardSpec(
+                    mesh_axes=tuple(mesh.shape.items())))
             return _shard.run_sharded(
                 be, spec, p, params, x, k=k, mesh=mesh, precision=precision,
                 epilogue=epilogue, bias=bias, residual=residual, fuse=fuse)

@@ -167,7 +167,7 @@ register_backend(
     "msgemm_pallas", modes=("msgemm",), run=run_msgemm_pallas,
     priority=lambda dev: 60 if dev == "tpu" else 40,
     tunable=("tm", "tj", "tb", "acc_in_vmem"),
-    epilogue_ok=_pallas_epilogue_ok,
+    epilogue_ok=_pallas_epilogue_ok, partitionable=False,
     description="fused VMEM-tiled produce+consume Pallas kernel "
                 "(amortized produce, VMEM acc stripe, fused epilogue)")
 
@@ -179,5 +179,5 @@ register_backend(
     "int4_pallas", modes=("int4_dequant",), run=run_int4_pallas, priority=40,
     codebooks=("none",),  # the blocked kernel dequantizes the uniform grid
     tunable=("tm", "tj", "tb", "acc_in_vmem"),
-    epilogue_ok=_pallas_epilogue_ok,
+    epilogue_ok=_pallas_epilogue_ok, partitionable=False,
     description="blocked dequant+dot Pallas kernel (kernels/int4_matmul)")

@@ -225,13 +225,10 @@ def _tracing_active() -> bool:
     heuristic; callers that want tuned plans pre-warm the cache outside
     the trace (collecting() + warm(), as the engine and serve CLI do)."""
     import jax
+    import jax.numpy as jnp
 
-    try:
-        return not jax.core.trace_state_clean()
-    except AttributeError:  # future jax: probe with a throwaway op
-        import jax.numpy as jnp
-
-        return isinstance(jnp.zeros(()), jax.core.Tracer)
+    # a throwaway op is staged into the ambient trace iff one is active
+    return isinstance(jnp.zeros(()), jax.core.Tracer)
 
 
 # ---------------------------------------------------------------- keys

@@ -56,6 +56,11 @@ class Backend:
     # core.epilogue.Epilogue *inside* its kernel (fused into the final
     # writeback)?  False -> dispatch.execute applies it unfused after run.
     epilogue_ok: Callable = _no_epilogue
+    # can XLA partition ``run``'s ops under GSPMD?  A compiled Pallas
+    # kernel is an opaque Mosaic custom call that it cannot partition, so
+    # under a mesh dispatch.execute runs it inside shard_map, replicated
+    # when the plan itself is unsharded.
+    partitionable: bool = True
     description: str = ""
 
     def priority_for(self, device_kind: str) -> int:
@@ -123,7 +128,7 @@ def register_backend(name: str, *, modes, run, is_available=_always,
                      priority: int = 0, d_range=(1, 4),
                      storages=("packed_idx", "packed_u8"),
                      codebooks=("none", "learned"), tunable=(),
-                     epilogue_ok=_no_epilogue,
+                     epilogue_ok=_no_epilogue, partitionable: bool = True,
                      description: str = "", overwrite: bool = False) -> Backend:
     """Register an execution backend.  Raises on duplicate names unless
     ``overwrite`` (tests use overwrite to shadow a backend temporarily)."""
@@ -134,7 +139,7 @@ def register_backend(name: str, *, modes, run, is_available=_always,
                  is_available=is_available, priority=priority,
                  d_range=tuple(d_range), storages=tuple(storages),
                  codebooks=tuple(codebooks), tunable=tuple(tunable),
-                 epilogue_ok=epilogue_ok,
+                 epilogue_ok=epilogue_ok, partitionable=partitionable,
                  description=description)
     _REGISTRY[name] = be
     return be

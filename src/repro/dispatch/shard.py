@@ -45,7 +45,6 @@ from jax.sharding import PartitionSpec as P
 from repro import obs
 from repro.core.epilogue import apply_epilogue
 from repro.distributed import collectives as coll
-from repro.distributed import compat
 from repro.distributed import sharding as shd
 from repro.kernels import ops as kops
 
@@ -434,6 +433,6 @@ def run_sharded(backend, spec, plan, params: dict, x, *, k: int, mesh,
         y = pending if out is None else out + pending
         return apply_epilogue(y, epilogue, bias=b_l, residual=r_l)
 
-    fn = compat.shard_map(local, mesh=mesh, in_specs=(in_specs,),
-                          out_specs=out_specs, check=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(in_specs,),
+                       out_specs=out_specs, check_vma=False)
     return fn(operands)

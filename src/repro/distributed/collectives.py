@@ -21,7 +21,7 @@ for contraction-chunk *i* with the msGeMM consume of chunk *i+1*.
 ``obs.perfmodel`` to rank pipelined plan variants without measuring
 every chunk count.
 
-Implemented with fully-manual shard_map (repro.distributed.compat): the
+Implemented with fully-manual ``jax.shard_map``: the
 gather axis carries the collectives, the model/tensor axes are pure
 per-shard layout.
 """
@@ -33,8 +33,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-from repro.distributed import compat
 
 
 def _ring_perm(n: int):
@@ -53,7 +51,7 @@ def ring_reduce_scatter(y, axis: str, *, axis_size: int | None = None,
     1/N-size block, every hop a separate ppermute the scheduler can
     slide under unrelated compute.
     """
-    n = axis_size if axis_size is not None else compat.axis_size(axis)
+    n = axis_size if axis_size is not None else jax.lax.axis_size(axis)
     if n == 1:
         return y
     dim = dim % y.ndim
@@ -86,7 +84,7 @@ def ring_all_gather(y, axis: str, *, axis_size: int | None = None,
     Device p contributes block p; output concatenates all N blocks along
     ``dim`` in axis order.  N-1 single-block hops.
     """
-    n = axis_size if axis_size is not None else compat.axis_size(axis)
+    n = axis_size if axis_size is not None else jax.lax.axis_size(axis)
     if n == 1:
         return y
     dim = dim % y.ndim
@@ -114,7 +112,7 @@ def ring_psum(y, axis: str, *, axis_size: int | None = None):
     carrying the whole partial).  Either way every hop is an independent
     ppermute that can overlap unrelated compute.
     """
-    n = axis_size if axis_size is not None else compat.axis_size(axis)
+    n = axis_size if axis_size is not None else jax.lax.axis_size(axis)
     if n == 1:
         return y
     if y.shape[-1] % n == 0:
@@ -187,15 +185,13 @@ def int8_all_gather(x: jnp.ndarray, mesh, spec: P, *, axis: str = "data"):
         return x
     # fully-manual shard_map over the leaf's own storage spec: the only
     # collectives inside are over `axis`; the model/tensor axes are pure
-    # layout (each shard just carries its slice through).  Partial-auto
-    # (manual_axes={axis}) would be tidier but trips the SPMD
-    # partitioner's manual-subgroup check on the older jax spelling.
+    # layout (each shard just carries its slice through).
     gather = functools.partial(
-        compat.shard_map, mesh=mesh, in_specs=(spec,), out_specs=out_spec,
-        check=False)
+        jax.shard_map, mesh=mesh, in_specs=(spec,), out_specs=out_spec,
+        check_vma=False)
     scatter = functools.partial(
-        compat.shard_map, mesh=mesh, in_specs=(out_spec,), out_specs=spec,
-        check=False)
+        jax.shard_map, mesh=mesh, in_specs=(out_spec,), out_specs=spec,
+        check_vma=False)
 
     @jax.custom_vjp
     def f(xs):

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.distributed import compat
-
 # Resolution priority: earlier names grab contested mesh axes first.
 PRIORITY = (
     "batch", "expert", "expert_out", "heads", "kvheads", "mlp", "vocab",
@@ -122,7 +120,7 @@ def use(mesh: Mesh, rules: str = "default"):
     prev = (_CTX.mesh, _CTX.rules)
     _CTX.mesh, _CTX.rules = mesh, rules
     try:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             yield
     finally:
         _CTX.mesh, _CTX.rules = prev

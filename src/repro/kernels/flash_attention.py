@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.mode import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -91,8 +93,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
 
     ``interpret=None`` auto-detects (compiled on TPU, interpreter off-TPU).
     Caller pads Sq % tq == 0 and Skv % tk == 0 (ops.py wrapper)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     B, H, Sq, dh = q.shape
     Hk, Skv = k.shape[1], k.shape[2]
     assert H % Hk == 0, (H, Hk)

@@ -26,26 +26,42 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.epilogue import Epilogue
+from repro.kernels.mode import resolve_interpret
 
 
-def _dequant_dot(u8_ref, scale_ref, x_ref, *, tk: int, scale_block: int,
+def _dequant_dot(u8_ref, scale_ref, xe_ref, xo_ref, *, scale_block: int,
                  acc_dtype):
-    """Unpack + §3.3 scales + one MXU dot for the current (TM, TK) tile —
-    shared by the fused and legacy kernels (bit-identical per k-step)."""
-    packed = u8_ref[...]  # (TM, TK//2) uint8, two codes per byte
-    hi = (packed >> 4) & 0xF
-    lo = packed & 0xF
-    codes = jnp.stack([hi, lo], axis=-1).reshape(packed.shape[0], tk)
-    c = codes.astype(jnp.int32)
-    vals = jnp.where(c <= 7, c, c - 16).astype(acc_dtype)  # b() map, §3.1
-    q = scale_ref[...].astype(acc_dtype)  # (TM, TK // scale_block)
-    w = (vals.reshape(packed.shape[0], tk // scale_block, scale_block)
-         * q[..., None]).reshape(packed.shape[0], tk)
-    x = x_ref[...].astype(acc_dtype)  # (TK, TB)
-    return jax.lax.dot(w, x, preferred_element_type=acc_dtype)
+    """Unpack + §3.3 scales + MXU dots for the current (TM, TK) tile —
+    shared by the fused and legacy kernels (bit-identical per k-step).
+
+    Mosaic keeps lanes whole, so the nibble pairs are never interleaved
+    back into one k axis: the high nibbles (even k) meet the even rows of
+    x and the low nibbles (odd k) the odd rows, in two dots.  The per-block
+    scales (SK, TM) expand to per-column scales by an exact 0/1 matmul."""
+    packed = u8_ref[...].astype(jnp.int32)  # (TM, TK//2), two codes/byte
+    tk2 = packed.shape[1]
+    q = scale_ref[...].astype(acc_dtype)  # (TK // scale_block, TM)
+    blk = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], tk2), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], tk2), 1)
+    out = None
+    for shift, odd, x_ref in ((4, 0, xe_ref), (0, 1, xo_ref)):
+        c = (packed >> shift) & 0xF
+        vals = jnp.where(c <= 7, c, c - 16).astype(acc_dtype)  # b(), §3.1
+        kk = 2 * col + odd  # k index of this nibble within the tile
+        sel = ((kk >= blk * scale_block)
+               & (kk < (blk + 1) * scale_block)).astype(acc_dtype)
+        s = jax.lax.dot_general(
+            q, sel, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=acc_dtype)  # (TM, TK//2)
+        part = jax.lax.dot_general(
+            x_ref[...].astype(acc_dtype), vals * s, (((1,), (1,)), ((), ())),
+            preferred_element_type=acc_dtype)  # (TB, TM)
+        out = part if out is None else out + part
+    return out
 
 
-def _kernel_fused(u8_ref, scale_ref, x_ref, *rest, tk: int, scale_block: int,
+def _kernel_fused(u8_ref, scale_ref, xe_ref, xo_ref, *rest, scale_block: int,
                   acc_dtype, nk: int, epilogue: Epilogue, has_bias: bool,
                   has_res: bool):
     refs = list(rest)
@@ -53,7 +69,7 @@ def _kernel_fused(u8_ref, scale_ref, x_ref, *rest, tk: int, scale_block: int,
     res_ref = refs.pop(0) if has_res else None
     y_ref, acc_ref = refs
     kstep = pl.program_id(2)
-    part = _dequant_dot(u8_ref, scale_ref, x_ref, tk=tk,
+    part = _dequant_dot(u8_ref, scale_ref, xe_ref, xo_ref,
                         scale_block=scale_block, acc_dtype=acc_dtype)
 
     @pl.when(kstep == 0)
@@ -75,7 +91,7 @@ def _kernel_fused(u8_ref, scale_ref, x_ref, *rest, tk: int, scale_block: int,
         y_ref[...] = total.astype(y_ref.dtype)
 
 
-def _kernel_legacy(u8_ref, scale_ref, x_ref, y_ref, *, tk: int,
+def _kernel_legacy(u8_ref, scale_ref, xe_ref, xo_ref, y_ref, *,
                    scale_block: int, acc_dtype):
     kstep = pl.program_id(2)
 
@@ -83,7 +99,7 @@ def _kernel_legacy(u8_ref, scale_ref, x_ref, y_ref, *, tk: int,
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    part = _dequant_dot(u8_ref, scale_ref, x_ref, tk=tk,
+    part = _dequant_dot(u8_ref, scale_ref, xe_ref, xo_ref,
                         scale_block=scale_block, acc_dtype=acc_dtype)
     y_ref[...] += part.astype(y_ref.dtype)
 
@@ -109,8 +125,15 @@ def int4_matmul_pallas(
     acc_in_vmem: bool = True,
     epilogue: Epilogue | None = None,
 ) -> jnp.ndarray:
-    if interpret is None:  # auto-detect: compiled on TPU, interpreter off-TPU
-        interpret = jax.default_backend() != "tpu"
+    """y (m, b) = epilogue(dequant(u8) @ x).
+
+    Compiled on TPU the tiles must follow the (8, 128) tiling: tm a
+    multiple of 128 (or all of m), tk // 2 a multiple of 128 (or all of
+    k // 2), tk // scale_block a multiple of 8 (or all of the scale
+    blocks), and tb a multiple of 8 (or all of b) — ops.int4_tiles picks
+    such tiles.  The kernel puts batch rows on sublanes and output
+    features on lanes; the layout changes happen here, outside it."""
+    interpret = resolve_interpret(interpret)
     ep = epilogue or Epilogue()
     m, k2 = u8.shape
     k, b = x.shape
@@ -119,55 +142,58 @@ def int4_matmul_pallas(
         tk = scale_block * max(1, 256 // scale_block)
     assert tk % scale_block == 0 and tk % 2 == 0
     assert m % tm == 0 and k % tk == 0 and b % tb == 0, (m, k, b, tm, tk, tb)
-    sk = tk // scale_block
-    out_dtype = jnp.dtype(ep.out_dtype) if ep.out_dtype else jnp.dtype(
-        acc_dtype)
-
+    sk, tk2 = tk // scale_block, tk // 2
+    acc = jnp.dtype(acc_dtype)
+    out_dtype = jnp.dtype(ep.out_dtype) if ep.out_dtype else acc
+    xr = x.T.reshape(b, k2, 2)
+    operands = [u8, scales.T, xr[..., 0], xr[..., 1]]  # x's even/odd k rows
+    in_specs = [
+        pl.BlockSpec((tm, tk2), lambda ib, im, ik: (im, ik)),
+        pl.BlockSpec((sk, tm), lambda ib, im, ik: (ik, im)),
+        pl.BlockSpec((tb, tk2), lambda ib, im, ik: (ib, ik)),
+        pl.BlockSpec((tb, tk2), lambda ib, im, ik: (ib, ik)),
+    ]
+    out_spec = pl.BlockSpec((tb, tm), lambda ib, im, ik: (ib, im))
     grid = (b // tb, m // tm, k // tk)
+    # the unpacked f32 tiles (codes, scales, weights per nibble half)
+    # dominate VMEM; ask for room beyond the default scoped limit
+    params = pltpu.CompilerParams(vmem_limit_bytes=int(min(
+        100 << 20, max(32 << 20, 16 * tm * tk2 * 4))))
     if not acc_in_vmem:
         assert ep.is_identity, \
             "the legacy path has no fused epilogue (ops.py applies it unfused)"
         kern = functools.partial(
-            _kernel_legacy, tk=tk, scale_block=scale_block,
-            acc_dtype=acc_dtype)
+            _kernel_legacy, scale_block=scale_block, acc_dtype=acc)
         return pl.pallas_call(
             kern,
             grid=grid,
-            in_specs=[
-                pl.BlockSpec((tm, tk // 2), lambda ib, im, ik: (im, ik)),
-                pl.BlockSpec((tm, sk), lambda ib, im, ik: (im, ik)),
-                pl.BlockSpec((tk, tb), lambda ib, im, ik: (ik, ib)),
-            ],
-            out_specs=pl.BlockSpec((tm, tb), lambda ib, im, ik: (im, ib)),
-            out_shape=jax.ShapeDtypeStruct((m, b), acc_dtype),
+            in_specs=in_specs,
+            out_specs=out_spec,
+            out_shape=jax.ShapeDtypeStruct((b, m), acc),
+            compiler_params=params,
             interpret=interpret,
-        )(u8, scales, x)
+        )(*operands).T
 
     has_bias, has_res = ep.bias, ep.residual
-    in_specs = [
-        pl.BlockSpec((tm, tk // 2), lambda ib, im, ik: (im, ik)),
-        pl.BlockSpec((tm, sk), lambda ib, im, ik: (im, ik)),
-        pl.BlockSpec((tk, tb), lambda ib, im, ik: (ik, ib)),
-    ]
-    operands = [u8, scales, x]
     if has_bias:
         assert bias is not None and bias.shape == (m, 1), (m, bias)
-        in_specs.append(pl.BlockSpec((tm, 1), lambda ib, im, ik: (im, 0)))
-        operands.append(bias)
+        in_specs.append(pl.BlockSpec((1, tm), lambda ib, im, ik: (0, im)))
+        operands.append(bias.T)
     if has_res:
         assert residual is not None and residual.shape == (m, b), \
             (m, b, residual)
-        in_specs.append(pl.BlockSpec((tm, tb), lambda ib, im, ik: (im, ib)))
-        operands.append(residual)
+        in_specs.append(pl.BlockSpec((tb, tm), lambda ib, im, ik: (ib, im)))
+        operands.append(residual.T)
     kern = functools.partial(
-        _kernel_fused, tk=tk, scale_block=scale_block, acc_dtype=acc_dtype,
+        _kernel_fused, scale_block=scale_block, acc_dtype=acc,
         nk=k // tk, epilogue=ep, has_bias=has_bias, has_res=has_res)
     return pl.pallas_call(
         kern,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((tm, tb), lambda ib, im, ik: (im, ib)),
-        out_shape=jax.ShapeDtypeStruct((m, b), out_dtype),
-        scratch_shapes=[pltpu.VMEM((tm, tb), jnp.dtype(acc_dtype))],
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct((b, m), out_dtype),
+        scratch_shapes=[pltpu.VMEM((tb, tm), acc)],
+        compiler_params=params,
         interpret=interpret,
-    )(*operands)
+    )(*operands).T

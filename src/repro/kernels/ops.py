@@ -20,6 +20,8 @@ while the LUT tile stays within ``VMEM_BUDGET``.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -27,14 +29,11 @@ from repro.core import packing
 from repro.core.epilogue import Epilogue
 from repro.kernels import int4_matmul as _i4
 from repro.kernels import msgemm as _ms
+from repro.kernels.mode import resolve_interpret
 
 VMEM_BUDGET = 8 * 1024 * 1024  # conservative per-step LUT budget (bytes)
 ACC_BUDGET = 4 * 1024 * 1024   # acc + out stripe budget (bytes)
 DECODE_BATCH = 32  # b <= this is treated as a decode shape (tall-skinny)
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _round_up(v: int, mult: int) -> int:
@@ -43,7 +42,7 @@ def _round_up(v: int, mult: int) -> int:
 
 def _pick_tiles(m: int, kc: int, b: int, d: int, scale_block: int,
                 out_bytes: int = 4, residual: bool = False):
-    """Pick (tm, tj, tb) fitting the 16^d LUT tile in the VMEM budget.
+    """Pick (tm, tj, tb) fitting the LUT tile in the VMEM budget.
 
     tj must stay a multiple of scale_block // d (factored-scale tiling,
     §3.3).  Growth doubles tj only while the doubled tile still divides
@@ -53,17 +52,21 @@ def _pick_tiles(m: int, kc: int, b: int, d: int, scale_block: int,
     gathered for nothing (e.g. kc=86, cpb=12 grew tj to 96 -> 10 dead
     chunk columns per row).
 
-    tb is sized to the actual batch (decode: round_up(b, 8), never padded
-    to 128) and shrunk while the fused kernel's VMEM acc+out stripe
-    (mp·tb·8 B) exceeds ACC_BUDGET.  Decode shapes (b <= DECODE_BATCH)
-    take tm up to 512: more rows per gather step against the same
-    resident LUT tile.
+    The kernel puts output features on lanes, so tm is a multiple of 128
+    (or all of a sub-128 m).  tb is sized to the actual batch (decode:
+    round_up(b, 8), never padded to 128) and shrunk while the fused
+    kernel's VMEM acc+out stripe (mp·tb·8 B) exceeds ACC_BUDGET, or
+    while even the narrowest LUT tile (one scale block of chunks) would
+    not fit VMEM_BUDGET.  Decode shapes (b <= DECODE_BATCH) take tm up
+    to 512: more rows per gather step against the same resident LUT
+    tile.
     """
-    n = 16**d
+    n = _ms.lut_width(d)
     cpb = scale_block // d
     tb = min(128, _round_up(b, 8))
     tm_cap = 512 if b <= DECODE_BATCH else 256
-    tm = min(tm_cap, _round_up(m, 8))
+    tm = (min(tm_cap, _round_up(m, 128)) if m > _ms.LANES
+          else _round_up(m, 8))
     # acc stripe (f32 acc + f32 out ~ 8 B/elem) must stay within budget —
     # but only shrink tb when some tb can actually satisfy it; if even the
     # tb floor cannot (vocab-sized m), the shape runs the legacy kernel
@@ -74,6 +77,8 @@ def _pick_tiles(m: int, kc: int, b: int, d: int, scale_block: int,
     if acc_stripe_fits(m, tm, 8, out_bytes, residual):
         while tb > 8 and not acc_stripe_fits(m, tm, tb, out_bytes, residual):
             tb = max(8, _round_up(tb // 2, 8))
+    while tb > 8 and n * cpb * tb * 4 > VMEM_BUDGET:
+        tb = max(8, _round_up(tb // 2, 8))
     tj = cpb
     # grow tj while the LUT tile (n * tj * tb * 4B) stays in budget and
     # the doubled tile still tiles kc exactly (tj <= kc, kc % tj == 0)
@@ -106,9 +111,14 @@ def msgemm_tiles(m: int, kc: int, b: int, d: int, scale_block: int):
 
 
 def int4_tiles(m: int, k: int, b: int, scale_block: int):
-    """Heuristic (tm, tk, tb) for the blocked int4 dequant kernel."""
-    tk = scale_block * max(1, 128 // scale_block)
-    tm = min(256, _round_up(m, 8))
+    """Heuristic (tm, tk, tb) for the blocked int4 dequant kernel, on the
+    TPU tiling: tk // 2 packed bytes fill whole 128-lane rows and the
+    tk // scale_block scale rows whole 8-row sublane groups — unless one
+    tile covers all of k, which any block may."""
+    tk = min(math.lcm(256, 8 * scale_block),
+             _round_up(k, math.lcm(2, scale_block)))
+    tm = (min(256, _round_up(m, 128)) if m > _ms.LANES
+          else _round_up(m, 8))
     tb = min(128, _round_up(b, 8))
     return tm, tk, tb
 
@@ -186,7 +196,7 @@ def msgemm(codes: jnp.ndarray, x: jnp.ndarray, d: int, *,
                   ((0, kcp * d - x.shape[0]), (0, bp - b)))
     sc_p = jnp.pad(scales.astype(jnp.float32),
                    ((0, mp - m), (0, sj - scales.shape[1])))
-    interpret = _interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
 
     fuse = acc_in_vmem and not ep.is_identity
     bias_p = res_p = None
@@ -237,7 +247,7 @@ def int4_matmul(u8: jnp.ndarray, scales: jnp.ndarray, x: jnp.ndarray, *,
     sc_p = jnp.pad(scales.astype(jnp.float32),
                    ((0, mp - m), (0, kp // scale_block - scales.shape[1])))
     x_p = jnp.pad(x.astype(jnp.float32), ((0, kp - k), (0, bp - b)))
-    interpret = _interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
 
     fuse = acc_in_vmem and not ep.is_identity
     bias_p = res_p = None
@@ -389,5 +399,5 @@ def flash_attention(q, k, v, *, causal=True, window=0, softcap=0.0,
     o = _fa.flash_attention_pallas(
         qt, kt, vt, causal=causal, window=window, softcap=softcap,
         tq=tq, tk=tk,
-        interpret=_interpret() if interpret is None else interpret)
+        interpret=resolve_interpret(interpret))
     return jnp.moveaxis(o, 1, 2)[:, :Sq]

@@ -37,37 +37,52 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.mode import resolve_interpret
+
 NEG_INF = -1e30
 
 
-def _decode_block(codes, scales, *, bits: int, codebook, head_dim: int):
-    """(bs, Dhp) u8 codes + (bs,) scales -> (bs, Dh) f32 values, all in
-    VMEM.  The codebook path reconstructs via a 16-way select chain on
-    scalar constants — no in-kernel gather, no captured array consts."""
+def _decode_block(codes, scales, *, bits: int, codebook):
+    """(bs, Dhp) u8 codes + (bs, 1) scales -> f32 values, all in VMEM.
+    8-bit codes give (bs, Dh).  4-bit codes give (bs, 2*Dhp) in the
+    kernel's nibble-major order — every high nibble (even head dims)
+    first, then every low nibble (odd dims) — so no lane interleave is
+    needed; the wrapper puts q and the output in the same order.  The
+    codebook path reconstructs via a 16-way select chain on scalar
+    constants — no in-kernel gather, no captured array consts."""
     c = codes.astype(jnp.int32)
     if bits == 8:
         vals = jnp.where(c < 128, c, c - 256).astype(jnp.float32)
     else:
-        hi, lo = (c >> 4) & 0xF, c & 0xF  # hi nibble first (pack_storage)
-        cc = jnp.stack([hi, lo], axis=-1).reshape(c.shape[0], -1)
-        cc = cc[:, :head_dim]
+        cc = jnp.concatenate([(c >> 4) & 0xF, c & 0xF], axis=1)
         if codebook is None:
             vals = jnp.where(cc <= 7, cc, cc - 16).astype(jnp.float32)
         else:
             # 16-way select chain over scalar constants: pallas_call
             # rejects captured *array* constants, and a chain of selects
-            # on the (bs, Dh) code tile is VPU-trivial next to the dot
+            # on the code tile is VPU-trivial next to the dot
             vals = jnp.zeros(cc.shape, jnp.float32)
             for j, entry in enumerate(codebook):
                 if entry:
                     vals = jnp.where(cc == j, jnp.float32(entry), vals)
-    return vals * scales.astype(jnp.float32)[:, None]
+    return vals * scales
+
+
+def _head_scales(tile, kv_head):
+    """Column ``kv_head`` of a (bs, Hk) scale tile, as (bs, 1) — a masked
+    lane sum, since the head index is only known at run time."""
+    if tile.shape[1] == 1:
+        return tile.astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+    return jnp.sum(jnp.where(lane == kv_head, tile, 0.0).astype(jnp.float32),
+                   axis=1, keepdims=True)
 
 
 def _kernel(bt_ref, q_ref, pos_ref, kc_ref, ks_ref, vc_ref, vs_ref, o_ref,
             m_scr, l_scr, acc_scr, *, bits: int, codebook,
-            block_size: int, nseq: int, head_dim: int, window: int,
+            block_size: int, nseq: int, group: int, window: int,
             softcap: float, scale: float):
+    kv_head = pl.program_id(1) // group
     i = pl.program_id(2)
 
     @pl.when(i == 0)
@@ -76,35 +91,37 @@ def _kernel(bt_ref, q_ref, pos_ref, kc_ref, ks_ref, vc_ref, vs_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr[...])
         acc_scr[...] = jnp.zeros_like(acc_scr[...])
 
-    qb = q_ref[...][0, :, 0, :].astype(jnp.float32) * scale  # (C, Dh)
-    k = _decode_block(kc_ref[...][0, :, 0, :], ks_ref[...][0, :, 0],
-                      bits=bits, codebook=codebook, head_dim=head_dim)
-    v = _decode_block(vc_ref[...][0, :, 0, :], vs_ref[...][0, :, 0],
-                      bits=bits, codebook=codebook, head_dim=head_dim)
-    s = qb @ k.T  # (C, bs)
+    qb = q_ref[...].astype(jnp.float32) * scale  # (C, W)
+    k = _decode_block(kc_ref[...], _head_scales(ks_ref[...], kv_head),
+                      bits=bits, codebook=codebook)
+    v = _decode_block(vc_ref[...], _head_scales(vs_ref[...], kv_head),
+                      bits=bits, codebook=codebook)
+    s = jax.lax.dot_general(qb, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)  # (C, bs)
     if softcap:
         s = softcap * jnp.tanh(s / softcap)
-    qpos = pos_ref[...][0]  # (C,)
-    kvpos = i * block_size + jax.lax.iota(jnp.int32, block_size)
-    ok = kvpos[None, :] <= qpos[:, None]
+    qpos = pos_ref[...]  # (C, 1)
+    kvpos = i * block_size + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_size), 1)
+    ok = kvpos <= qpos
     if window:
-        ok &= kvpos[None, :] > qpos[:, None] - window
+        ok &= kvpos > qpos - window
     s = jnp.where(ok, s, NEG_INF)
 
-    m, l = m_scr[...][:, 0], l_scr[...][:, 0]
-    m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m, l = m_scr[...], l_scr[...]  # (C, 1)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m - m_new)
-    l_new = corr * l + jnp.sum(p, axis=-1)
-    acc_new = corr[:, None] * acc_scr[...] + p @ v
-    m_scr[...] = m_new[:, None]
-    l_scr[...] = l_new[:, None]
+    l_new = corr * l + jnp.sum(p, axis=-1, keepdims=True)
+    acc_new = corr * acc_scr[...] + jnp.dot(
+        p, v, preferred_element_type=jnp.float32)
+    m_scr[...] = m_new
+    l_scr[...] = l_new
     acc_scr[...] = acc_new
 
     @pl.when(i == nseq - 1)
     def _writeback():
-        o_ref[...] = (acc_new / jnp.maximum(l_new, 1e-30)[:, None]
-                      )[None, :, None, :].astype(o_ref.dtype)
+        o_ref[...] = (acc_new / jnp.maximum(l_new, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -121,43 +138,60 @@ def paged_attention_pallas(q, k_codes, k_scales, v_codes, v_scales,
     int32 logical query positions.  Returns (B, C, H, Dh) in q.dtype.
 
     ``codebook`` is the spec's static 16-float tuple (None: int grid) —
-    embedded as a compile-time constant, consumed from VMEM per block."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    embedded as a compile-time constant, consumed from VMEM per block.
+
+    The kernel sees one (query head, KV block) pair per step: q and the
+    output as (C, Dh) tiles of a (B, H, C, Dh) transpose, the codes as
+    (bs, Dhp) lane slices of the (nb, bs, Hk*Dhp) pool — compiled on TPU
+    that needs Dhp % 128 == 0 unless Hk == 1 — and the scales as whole
+    (bs, Hk) rows."""
+    interpret = resolve_interpret(interpret)
     B, C, H, dh = q.shape
     nb, bs, hk, dhp = k_codes.shape
     assert bs == block_size, (bs, block_size)
     assert H % hk == 0, (H, hk)
     g = H // hk
     nseq = block_tables.shape[1]
+    qt = jnp.swapaxes(q, 1, 2)  # (B, H, C, Dh)
+    if bits == 4:  # nibble-major head dims: evens, then odds (padded)
+        odd = qt[..., 1::2]
+        qt = jnp.concatenate(
+            [qt[..., 0::2], jnp.pad(odd, ((0, 0),) * 3
+                                    + ((0, dhp - odd.shape[-1]),))], -1)
+    width = qt.shape[-1]
     kern = functools.partial(
         _kernel, bits=bits, codebook=codebook, block_size=block_size,
-        nseq=nseq, head_dim=dh, window=window, softcap=softcap,
-        scale=dh**-0.5)
-    code_spec = pl.BlockSpec((1, bs, 1, dhp),
-                             lambda b, h, i, bt: (bt[b, i], 0, h // g, 0))
-    scale_spec = pl.BlockSpec((1, bs, 1),
-                              lambda b, h, i, bt: (bt[b, i], 0, h // g))
+        nseq=nseq, group=g, window=window, softcap=softcap, scale=dh**-0.5)
+    code_spec = pl.BlockSpec((None, bs, dhp),
+                             lambda b, h, i, bt: (bt[b, i], 0, h // g))
+    scale_spec = pl.BlockSpec((None, bs, hk),
+                              lambda b, h, i, bt: (bt[b, i], 0, 0))
+    head_spec = pl.BlockSpec((None, None, C, width),
+                             lambda b, h, i, bt: (b, h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, H, nseq),
         in_specs=[
-            pl.BlockSpec((1, C, 1, dh), lambda b, h, i, bt: (b, 0, h, 0)),
-            pl.BlockSpec((1, C), lambda b, h, i, bt: (b, 0)),
+            head_spec,
+            pl.BlockSpec((None, C, 1), lambda b, h, i, bt: (b, 0, 0)),
             code_spec, scale_spec, code_spec, scale_spec,
         ],
-        out_specs=pl.BlockSpec((1, C, 1, dh),
-                               lambda b, h, i, bt: (b, 0, h, 0)),
+        out_specs=head_spec,
         scratch_shapes=[
-            pltpu.VMEM((C, 1), jnp.float32),   # running max m
-            pltpu.VMEM((C, 1), jnp.float32),   # running denom l
-            pltpu.VMEM((C, dh), jnp.float32),  # output accumulator
+            pltpu.VMEM((C, 1), jnp.float32),      # running max m
+            pltpu.VMEM((C, 1), jnp.float32),      # running denom l
+            pltpu.VMEM((C, width), jnp.float32),  # output accumulator
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C, H, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, C, width), q.dtype),
         interpret=interpret,
-    )(jnp.asarray(block_tables, jnp.int32), q,
-      jnp.asarray(positions, jnp.int32), k_codes, k_scales,
-      v_codes, v_scales)
+    )(jnp.asarray(block_tables, jnp.int32), qt,
+      jnp.asarray(positions, jnp.int32)[..., None],
+      k_codes.reshape(nb, bs, hk * dhp), k_scales,
+      v_codes.reshape(nb, bs, hk * dhp), v_scales)
+    if bits == 4:  # back to interleaved head dims
+        out = jnp.stack([out[..., :dhp], out[..., dhp:]], -1)
+        out = out.reshape(B, H, C, 2 * dhp)[..., :dh]
+    return jnp.swapaxes(out, 1, 2)

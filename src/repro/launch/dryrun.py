@@ -215,8 +215,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool, quant: str,
         t_compile = time.time() - t0
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # older jax: one dict per computation
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     coll = parse_collectives(hlo)
     n_dev = mesh_devices(mesh)

@@ -29,7 +29,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     axis (data-parallel only), 'model' stays intra-pod ICI."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple, axes: tuple):
@@ -37,17 +37,24 @@ def make_mesh(shape: tuple, axes: tuple):
 
     Unlike ``jax.make_mesh`` this accepts a shape smaller than the
     visible device count (a 2x2 sweep entry on an 8-device host uses the
-    first 4 devices) — what the serve/bench ``--mesh`` sweeps need."""
+    first 4 devices) — what the serve/bench ``--mesh`` sweeps need.
+
+    Every axis is ``Auto``: the model code lays arrays out with
+    ``with_sharding_constraint`` under GSPMD, which ``jax.make_mesh``'s
+    default ``Explicit`` axes turn into layout assertions."""
     import numpy as np
+    from jax.sharding import AxisType
 
     shape, axes = tuple(shape), tuple(axes)
     n = int(np.prod(shape))
+    auto = (AxisType.Auto,) * len(axes)
     devs = jax.devices()
     if n < len(devs):
         from jax.sharding import Mesh
 
-        return Mesh(np.asarray(devs[:n]).reshape(shape), axes)
-    return jax.make_mesh(shape, axes)
+        return Mesh(np.asarray(devs[:n]).reshape(shape), axes,
+                    axis_types=auto)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def mesh_devices(mesh) -> int:

@@ -38,7 +38,6 @@ from repro import configs, dispatch, obs
 from repro.core.spec import QuantSpec
 from repro.distributed import sharding as shd
 from repro.models import transformer as T
-from repro.quant import quantize_model
 from repro.runtime import serve as SV
 
 
@@ -46,11 +45,13 @@ def build_model(args):
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get_config(args.arch))
     key = jax.random.PRNGKey(args.seed)
-    params = T.init_params(key, cfg)
     if args.quant != "bf16":
         spec = QuantSpec(mode=args.quant, d=args.d, scale_block=12 * args.d)
-        params = quantize_model(params, cfg, spec)
         cfg = cfg.replace(quant=spec)
+    # one program; each linear is quantized as its layer group is drawn,
+    # so a full-width model never holds its dense weights at once
+    params = jax.jit(lambda k: T.init_params(k, cfg))(key)
+    if args.quant != "bf16":
         print(f"[serve] quantized weights to {args.quant} (d={args.d})")
     return params, cfg, key
 
@@ -389,7 +390,10 @@ def main(argv=None):
                          "$REPRO_CALIBRATION or the user cache dir)")
     args = ap.parse_args(argv)
 
+    from repro.launch.cache import use_compile_cache
     from repro.launch.mesh import force_host_devices
+
+    use_compile_cache()
 
     force_host_devices(args.force_host_devices)
     mesh = parse_mesh(args.mesh) if args.mesh else None

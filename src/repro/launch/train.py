@@ -18,6 +18,7 @@ import jax
 from repro import configs
 from repro.data import DataConfig, SyntheticStream
 from repro.distributed import sharding as shd
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.optim import AdamWConfig, schedules
 from repro.runtime import train as RT
@@ -46,6 +47,7 @@ def main(argv=None):
     ap.add_argument("--checkpoint-every", type=int, default=50)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get_config(args.arch))

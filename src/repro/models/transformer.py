@@ -172,8 +172,10 @@ def _stack_init(key, cfg: ModelConfig, pattern, groups: int, *,
     out = {}
     for i, kind in enumerate(pattern):
         keys = jax.random.split(jax.random.fold_in(key, i), groups)
-        out[f"{i}:{kind}"] = jax.vmap(
-            lambda k: block_init(k, cfg, kind, cross=cross))(keys)
+        # one group at a time: a quantized config then holds one group's
+        # dense weights, not the whole stack's, while it quantizes them
+        out[f"{i}:{kind}"] = jax.lax.map(
+            lambda k: block_init(k, cfg, kind, cross=cross), keys)
     return out
 
 

@@ -8,11 +8,11 @@ fraction.  Conventions match the roofline module (1 MAC = 2 FLOPs;
 LUT-consume table adds = 1 op each, retired on the vector unit on
 current TPUs — the paper §6 limiting factor).
 
-Hardware table is keyed by ``jax.default_backend()``.  The tpu entry is
-the tpu-v5e-class chip used throughout EXPERIMENTS.md; the cpu/gpu
-entries are deliberately rough — on CPU the "fraction" column is only
-useful for relative comparison between shapes, and the microbench
-records which hardware model priced each row.
+The peak table is keyed by ``jax.Device.device_kind``, each row with its
+source.  A device kind that is not in the table is an error: a chip is
+never priced with another device's peaks.  The cpu row is deliberately
+rough — on CPU the "fraction" column only compares shapes with each
+other — and the microbench records which row priced each result.
 """
 
 from __future__ import annotations
@@ -32,21 +32,28 @@ class Device:
 
 
 DEVICES = {
-    # tpu-v5e-class, mirrored from benchmarks/roofline.Hardware
-    "tpu": Device("tpu-v5e-class", 197e12, 4e12, 819e9),
-    # a100-class single die (PAPERS.md Tensor Core study numbers)
-    "gpu": Device("a100-class", 312e12, 19.5e12, 1555e9),
-    # honest-but-rough host numbers: one AVX2 socket-ish
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+    # HBM.  The vector-unit rate is not published; 4e12 op/s is the
+    # estimate benchmarks/roofline.Hardware uses.
+    "TPU v5 lite": Device("TPU v5e", 197e12, 4e12, 819e9),
+    # rough host numbers, one AVX2 socket-ish: shape-to-shape only
     "cpu": Device("cpu-host", 1e11, 5e10, 3e10),
 }
 
 
-def device(backend: str | None = None) -> Device:
-    if backend is None:
+def device(kind: str | None = None) -> Device:
+    """The peak row for a ``device_kind`` (default: this process's first
+    device).  Raises KeyError for a kind without a row."""
+    if kind is None:
         import jax
 
-        backend = jax.default_backend()
-    return DEVICES.get(backend, DEVICES["cpu"])
+        kind = jax.devices()[0].device_kind
+    try:
+        return DEVICES[kind]
+    except KeyError:
+        raise KeyError(
+            f"no peak numbers for device kind {kind!r}; add a row with "
+            f"its source to repro.obs.costs.DEVICES") from None
 
 
 def produce_table_ops(d: int) -> float:

@@ -83,11 +83,9 @@ def _ceil_div(a: int, b: int) -> int:
 
 def effective_interpret(interpret: bool | None) -> bool:
     """Resolve interpret=None exactly like the kernel wrappers do."""
-    if interpret is not None:
-        return bool(interpret)
-    import jax
+    from repro.kernels.mode import resolve_interpret
 
-    return jax.default_backend() != "tpu"
+    return resolve_interpret(interpret)
 
 
 def current_partition() -> tuple[str, bool]:
@@ -95,8 +93,7 @@ def current_partition() -> tuple[str, bool]:
     every fresh measurement in this process belongs to."""
     import jax
 
-    dev = jax.default_backend()
-    return dev, dev != "tpu"
+    return jax.default_backend(), effective_interpret(None)
 
 
 # =====================================================================
@@ -429,7 +426,10 @@ def _fallback_constants(device: str) -> dict[str, float]:
     overhead) so predict() always returns *something* ordered."""
     from repro.obs import costs
 
-    dev = costs.DEVICES.get(device, costs.DEVICES["cpu"])
+    import jax
+
+    # partitions are keyed by backend; price this process's own chip
+    dev = costs.device(None if device == jax.default_backend() else device)
     return {"launch_s": 0.0, "step_s": 0.0,
             "produce_s_per_flop": 1.0 / dev.matmul_flops,
             "consume_s_per_op": 1.0 / dev.vector_flops,

@@ -46,7 +46,7 @@ def compressed_pmean_tree(grads, axis_name: str, residual=None):
     Returns (mean_grads, new_residual).  Must run where `axis_name` is a
     manual (shard_map) axis.
     """
-    n = jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
     if residual is None:
         residual = jax.tree.map(lambda g: jnp.zeros(g.shape, jnp.float32),
                                 grads)

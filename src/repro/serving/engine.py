@@ -271,13 +271,12 @@ class Engine:
         self._step_fn = jax.jit(raw_step, donate_argnums=(1,))
 
         # execution planning: resolve every linear's ExecPlan once, at
-        # build — never per step.  With no backend/autotune request and
-        # no mesh the policy is None and behavior is exactly the
-        # per-config default.  A mesh always triggers build-time
-        # resolution (the warm-up is how sharded plans + their cache
-        # keys come into existence before the trace).
+        # build — never per step — so ``exec_plans`` always names what
+        # will run.  With no backend/autotune request and no mesh the
+        # policy is None and the plans are exactly the per-config
+        # default.  Under a mesh the warm-up is how sharded plans + their
+        # cache keys come into existence before the trace.
         self._policy = None
-        self.exec_plans: dict = {}
         if backend is not None or autotune or mesh is not None:
             if autotune_cache is not None:
                 dispatch.set_cache_path(autotune_cache)
@@ -285,7 +284,7 @@ class Engine:
                 backend=backend, autotune=autotune,
                 shard_collective=shard_collective,
                 shard_pipeline=shard_pipeline, shard_impl=shard_impl)
-            self.exec_plans = self._resolve_plans(raw_step)
+        self.exec_plans: dict = self._resolve_plans(raw_step)
 
     def _export_kv_gauges(self, num_blocks: int, cache_dtype) -> None:
         """Pool-capacity gauges (kv_* prefix, NOT serving_*: capacity is
@@ -466,11 +465,6 @@ class Engine:
             "serving_replans_total",
             help="step replans after hang/NaN escalation",
             reason=reason).inc()
-        if not self.exec_plans:
-            # plans were never resolved at build (no backend/autotune/
-            # mesh): resolve now so the suspects are known by name
-            with contextlib.suppress(Exception):
-                self.exec_plans = self._resolve_plans(self._raw_step)
         safe = {"dense", "dense_fallback"}
         suspects = sorted({p.backend for p in self.exec_plans.values()}
                           - safe)

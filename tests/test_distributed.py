@@ -104,16 +104,16 @@ def test_int8_all_gather_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.distributed import compat
         from repro.distributed.collectives import int8_all_gather
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 6)) * 0.3
         spec = P("data", "model")
         xs = jax.device_put(x, NamedSharding(mesh, spec))
         def f(x):
             g = int8_all_gather(x, mesh, spec, axis="data")
             return g, jnp.sum(g * jnp.arange(48.0).reshape(8, 6))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out = jax.jit(lambda x: f(x)[0])(xs)
             err = float(jnp.max(jnp.abs(out - x)))
             assert err <= float(jnp.max(jnp.abs(x))) / 127 + 1e-6, err

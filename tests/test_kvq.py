@@ -17,6 +17,7 @@ from repro.kvq.pool import init_kv_pool
 from repro.kvq.quantize import (kv_dequantize, kv_quantize, pack_codes,
                                 unpack_codes)
 from repro.kvq.spec import KVQuantSpec
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.serving import Engine, Request
@@ -343,7 +344,7 @@ def test_sharded_engine_quantized_pool(params):
     """8-host-device mesh serving with a quantized pool: tokens match
     the single-device quantized engine (the jnp backend lowers through
     GSPMD with the constrain'd pool layouts)."""
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     prompts = _prompts((5, 9), seed=6)
     base, _ = _generate(params, CFG, prompts, kv_quant=KVQuantSpec(8),
                         max_slots=4)

@@ -251,6 +251,13 @@ def test_costs_roofline_annotation():
     row = costs.annotate(1e-3, 2048, 768, 8, quant="msgemm", d=3,
                          dev=costs.DEVICES["cpu"])
     assert row["attainable_s"] > 0
+    # peaks are keyed by device_kind; an unknown kind is an error, never
+    # a fall back to another device's numbers
+    assert costs.device() is costs.DEVICES[jax.devices()[0].device_kind]
+    v5e = costs.device("TPU v5 lite")
+    assert (v5e.matmul_flops, v5e.mem_bw) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="TPU v9"):
+        costs.device("TPU v9")
     assert 0 < row["roofline_fraction"] <= 1.0 or row["measured_s"] == 0
     dense = costs.gemm_cost(2048, 768, 8, quant="dense")
     assert dense["produce_flops"] == 2 * 2048 * 768 * 8

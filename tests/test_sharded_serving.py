@@ -22,6 +22,7 @@ Acceptance invariants covered here:
   tunes the variant grid once into the additive shard_variants table.
 """
 
+import functools
 import os
 import subprocess
 import sys
@@ -34,6 +35,7 @@ from repro import dispatch
 from repro.core.spec import QuantSpec
 from repro.dispatch import autotune as at
 from repro.dispatch.shard import ShardSpec, shard_spec_for
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 from repro.quant import quantize_model
@@ -58,7 +60,7 @@ SPEC = QuantSpec(mode="msgemm", d=2, scale_block=8)
 
 
 def _mesh():
-    return jax.make_mesh((2, 4), ("data", "model"))
+    return make_mesh((2, 4), ("data", "model"))
 
 
 def _prompts(lens, seed=0, vocab=64):
@@ -399,10 +401,10 @@ def test_ring_collectives_match_xla():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed import collectives as coll, compat
+    from repro.distributed import collectives as coll
 
-    mesh = jax.make_mesh((4,), ("model",))
-    sm = compat.shard_map
+    mesh = make_mesh((4,), ("model",))
+    sm = functools.partial(jax.shard_map, check_vma=False)
     x = jnp.arange(4 * 8 * 16, dtype=jnp.float32).reshape(4, 8, 16)
 
     def pair(fn, ref, arr):

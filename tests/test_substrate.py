@@ -117,10 +117,11 @@ def test_compressed_psum_shard_map():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
         import jax, jax.numpy as jnp, numpy as np, functools
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_mesh
         from repro.optim import compression
-        mesh = jax.make_mesh((2,), ("pod",))
+        mesh = make_mesh((2,), ("pod",))
         x = jnp.arange(8, dtype=jnp.float32).reshape(2, 4) / 7.0
         f = shard_map(lambda s: compression.compressed_psum(s, "pod"),
                       mesh=mesh, in_specs=P("pod", None),

@@ -101,3 +101,49 @@ def test_long_decode_states_bounded():
     b64 = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(c64))
     b4096 = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(c4096))
     assert b64 == b4096  # state size independent of context length
+
+
+def test_quantized_init_matches_quantize_model():
+    """Drawing a quantized config directly (what the serve CLI does, so a
+    full-width model never holds its dense tree) builds the same tree,
+    bit for bit, as quantizing the dense init in one program.  (Op by op
+    outside jit, XLA may round a scale differently by one ulp.)"""
+    from repro.core.spec import QuantSpec
+
+    spec = QuantSpec(mode="msgemm", d=3, scale_block=36)
+    key = jax.random.PRNGKey(5)
+    direct = jax.jit(lambda k: T.init_params(k, CFG.replace(quant=spec)))(key)
+    ref = jax.jit(lambda k: quantize_model(T.init_params(k, CFG), CFG,
+                                           spec))(key)
+    assert jax.tree.structure(direct) == jax.tree.structure(ref)
+    for a, b in zip(jax.tree.leaves(direct), jax.tree.leaves(ref)):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
+    entry points cache at one fixed, git-ignored directory in the
+    checkout."""
+    import pathlib
+
+    from repro.launch import cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    try:
+        got = cache.use_compile_cache()
+        root = pathlib.Path(__file__).resolve().parents[1]
+        if env_dir is None:
+            assert got == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            assert ".jax_cache/" in (root / ".gitignore").read_text()
+        else:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == prev
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
