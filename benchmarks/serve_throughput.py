@@ -58,7 +58,9 @@ SHARD_JSON = Path(__file__).parent / "results" / "BENCH_shard.json"
 #       the single-device engine at EQUAL PER-DEVICE batch (max_slots /
 #       data-axis size) — the bar the CI --gate compares mesh throughput
 #       against
-BENCH_SERVE_SCHEMA = 4
+#   5 — the mesh sweep's "overlap" block is gone: it read host-callback
+#       marks staged into the jitted step, which timed the host
+BENCH_SERVE_SCHEMA = 5
 
 CFG = ModelConfig(num_layers=4, d_model=256, num_heads=8, num_kv_heads=4,
                   d_ff=1024, vocab_size=8192, max_seq_len=512)
@@ -288,75 +290,23 @@ def run_autotune(cache_path=None) -> list[str]:
     return lines
 
 
-def _interval_union(ivs: list) -> list:
-    """Merge [start, end) intervals into a disjoint sorted union."""
-    out: list = []
-    for a, b in sorted(ivs):
-        if out and a <= out[-1][1]:
-            out[-1][1] = max(out[-1][1], b)
-        else:
-            out.append([a, b])
-    return out
-
-
-def span_overlap(events: list) -> dict:
-    """Overlap attribution from a slice of trace events: how much of the
-    shard.collective.* span time is wall-clock-covered by shard.compute.*
-    spans.  All devices' jit-mark callbacks funnel into one host
-    timeline, so the fraction includes cross-device interleave (device
-    A's collective under device B's compute) as well as the pipelined
-    path's intra-device overlap (chunk i's ring issued before chunk
-    i+1's consume) — it measures how much collective time the schedule
-    actually hid under compute, whatever the mechanism."""
-    comp, coll = [], []
-    for ev in events:
-        if ev.get("ph") != "X":
-            continue
-        name = ev.get("name", "")
-        iv = (float(ev["ts"]), float(ev["ts"]) + float(ev.get("dur", 0.0)))
-        if name.startswith("shard.compute."):
-            comp.append(iv)
-        elif name.startswith("shard.collective."):
-            coll.append(iv)
-    comp_u, coll_u = _interval_union(comp), _interval_union(coll)
-    coll_us = sum(b - a for a, b in coll_u)
-    comp_us = sum(b - a for a, b in comp_u)
-    overlap_us = 0.0
-    for a, b in coll_u:
-        for x, y in comp_u:
-            lo, hi = max(a, x), min(b, y)
-            if lo < hi:
-                overlap_us += hi - lo
-    return {"compute_us": comp_us, "collective_us": coll_us,
-            "overlap_us": overlap_us,
-            "overlap_fraction": overlap_us / coll_us if coll_us else 0.0}
-
-
 def run_mesh_sweep(meshes: list[str], n=8, new_tokens=8,
-                   trace_out=None, gate=False) -> list[str]:
+                   gate=False) -> list[str]:
     """--mesh sweep: drive the continuous engine tensor-parallel over
     each requested mesh ('model=4,data=2' strings) in TWO variants —
     one_shot (the classic consume-then-collective) and pipelined (the
     chunked contraction whose ring collective overlaps the next chunk's
     LUT consume) — assert every variant's greedy tokens are identical to
-    the single-device baseline, and write throughput + plan stats +
-    per-variant overlap fractions to BENCH_shard.json (schema 4).
-
-    Tracing is always on during the sweep (the overlap fraction is
-    computed from the shard.compute.* / shard.collective.* spans of each
-    variant's own event slice); ``trace_out`` additionally writes the
-    whole sweep's Chrome-trace file.
+    the single-device baseline, and write throughput + plan stats to
+    BENCH_shard.json (schema 5).
 
     ``gate`` turns the acceptance claims into a hard exit status:
-    pipelined must beat one_shot on the first mesh with a non-zero
-    overlap fraction, and the best mesh throughput must be >= the
-    single-device engine at EQUAL PER-DEVICE batch."""
+    pipelined must beat one_shot on the first mesh, and the best mesh
+    throughput must be >= the single-device engine at EQUAL PER-DEVICE
+    batch."""
     from repro.launch.mesh import mesh_devices
     from repro.launch.serve import parse_mesh
     from repro.serving import Engine, poisson_stream
-
-    # must precede engine builds: jit marks are staged at trace time
-    obs.enable_tracing(clear=True)
 
     key = jax.random.PRNGKey(0)
     params = T.init_params(key, CFG)
@@ -379,16 +329,12 @@ def run_mesh_sweep(meshes: list[str], n=8, new_tokens=8,
         eng = Engine(p, c, **kw, mesh=mesh, **extra)
         eng.run(poisson_stream(2, c.vocab_size, max_new_tokens=2, seed=1))
         eng.reset_metrics()
-        jax.effects_barrier()  # settle warmup's jit-mark callbacks
-        ev0 = len(obs.tracer().events())
         res = eng.run(stream())
-        jax.effects_barrier()  # flush the measured run's callbacks
-        events = obs.tracer().events()[ev0:]
         toks = {rid: seq.generated for rid, seq in res.items()}
         return (eng, toks,
-                {**eng.summary(), "queue_depth": _queue_depth()}, events)
+                {**eng.summary(), "queue_depth": _queue_depth()})
 
-    _, base_toks, base_s, _ = drive(None)
+    _, base_toks, base_s = drive(None)
     lines = ["name,us_per_call,derived",
              f"serve_throughput/shard/baseline,"
              f"{1e6 / base_s['tok_per_s']:.1f},"
@@ -407,7 +353,7 @@ def run_mesh_sweep(meshes: list[str], n=8, new_tokens=8,
         key_ = str(dsz)
         if key_ in per_dev_base or dsz == 1:
             continue
-        _, _, s, _ = drive(None, max_slots=slots)
+        _, _, s = drive(None, max_slots=slots)
         per_dev_base[key_] = {"max_slots": slots, **s}
         lines.append(
             f"serve_throughput/shard/baseline_slots{slots},"
@@ -430,13 +376,12 @@ def run_mesh_sweep(meshes: list[str], n=8, new_tokens=8,
     for mesh_str in meshes:
         mesh = parse_mesh(mesh_str)
         for vname, vkw in VARIANTS:
-            eng, toks, s, events = drive(mesh, **vkw)
+            eng, toks, s = drive(mesh, **vkw)
             identical = toks == base_toks
             n_sharded = sum(1 for pl in eng.exec_plans.values()
                             if pl.shard is not None)
             n_piped = sum(1 for pl in eng.exec_plans.values()
                           if pl.shard is not None and pl.shard.is_pipelined)
-            ov = span_overlap(events)
             winners = sorted({f"{pl.shard.pipeline_chunks}."
                               f"{pl.shard.collective_impl}"
                               for pl in eng.exec_plans.values()
@@ -450,14 +395,12 @@ def run_mesh_sweep(meshes: list[str], n=8, new_tokens=8,
                          "tokens_identical": identical,
                          "plans": len(eng.exec_plans),
                          "sharded_plans": n_sharded,
-                         "pipelined_plans": n_piped,
-                         "overlap": ov, **s})
+                         "pipelined_plans": n_piped, **s})
             lines.append(
                 f"serve_throughput/shard/{mesh_str}/{vname},"
                 f"{1e6 / s['tok_per_s']:.1f},"
                 f"tok_per_s={s['tok_per_s']:.1f} sharded_plans={n_sharded} "
                 f"pipelined_plans={n_piped} "
-                f"overlap={ov['overlap_fraction']:.3f} "
                 f"tokens_identical={identical}")
             if not identical:
                 raise SystemExit(
@@ -473,10 +416,6 @@ def run_mesh_sweep(meshes: list[str], n=8, new_tokens=8,
          "baseline": base_s, "per_device_baselines": per_dev_base,
          "runs": runs}, indent=2))
     lines.append(f"serve_throughput/shard/json,0.0,{SHARD_JSON}")
-    if trace_out:
-        obs.tracer().save(trace_out)
-        lines.append(f"serve_throughput/shard/trace,0.0,{trace_out}")
-    obs.disable_tracing()
     if gate:
         lines += _gate_mesh_sweep(meshes[0], runs, per_dev_base)
     return lines
@@ -488,10 +427,7 @@ def _gate_mesh_sweep(gate_mesh: str, runs: list, per_dev_base: dict
     1 on any failed claim):
 
     1. on ``gate_mesh`` the pipelined variant beats one_shot (tok/s);
-    2. the winning pipelined run overlapped compute with its collectives
-       (overlap_fraction > 0) — the trace proves the mechanism, not just
-       the outcome;
-    3. some mesh run reaches the single-device engine at equal
+    2. some mesh run reaches the single-device engine at equal
        per-device batch (the ROADMAP 'mesh serving pays for itself'
        bar).  The bar is scaled by the host's attainable parallel
        fraction min(1, cores / mesh devices): a host that multiplexes V
@@ -508,10 +444,6 @@ def _gate_mesh_sweep(gate_mesh: str, runs: list, per_dev_base: dict
         problems.append(
             f"pipelined {pipe['tok_per_s']:.2f} tok/s did not beat "
             f"one_shot {one['tok_per_s']:.2f} tok/s on {gate_mesh}")
-    if pipe["overlap"]["overlap_fraction"] <= 0:
-        problems.append(
-            f"pipelined run on {gate_mesh} shows zero compute/collective "
-            f"overlap in its trace spans")
     cores = os.cpu_count() or 1
     bar = max((b["tok_per_s"] for b in per_dev_base.values()), default=0.0)
 
@@ -533,7 +465,6 @@ def _gate_mesh_sweep(gate_mesh: str, runs: list, per_dev_base: dict
     return [f"serve_throughput/shard/gate,0.0,passed "
             f"pipelined={pipe['tok_per_s']:.2f} "
             f"one_shot={one['tok_per_s']:.2f} "
-            f"overlap={pipe['overlap']['overlap_fraction']:.3f} "
             f"best={best['tok_per_s']:.2f} "
             f"per_device_bar={adjusted_bar(best):.2f}"]
 
@@ -550,13 +481,10 @@ def main(argv=None) -> int:
     ap.add_argument("--mesh", action="append", default=None,
                     help="mesh sweep entry, e.g. 'model=4,data=2' "
                          "(repeatable); emits BENCH_shard.json")
-    ap.add_argument("--trace-out", default=None,
-                    help="with --mesh: write a Chrome-trace JSON of the "
-                         "sweep (compute vs collective attribution)")
     ap.add_argument("--gate", action="store_true",
                     help="with --mesh: exit non-zero unless pipelined "
-                         "beats one_shot with overlap > 0 on the first "
-                         "mesh AND the best mesh matches the "
+                         "beats one_shot on the first mesh AND the best "
+                         "mesh matches the "
                          "single-device engine at equal per-device batch")
     ap.add_argument("--force-host-devices", type=int, default=0,
                     help="fake N host CPU devices (must be set before "
@@ -566,8 +494,7 @@ def main(argv=None) -> int:
 
     force_host_devices(args.force_host_devices)
     if args.mesh:
-        lines = run_mesh_sweep(args.mesh, trace_out=args.trace_out,
-                               gate=args.gate)
+        lines = run_mesh_sweep(args.mesh, gate=args.gate)
     elif args.autotune:
         lines = run_autotune(args.cache)
     else:

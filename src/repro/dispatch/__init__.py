@@ -146,18 +146,8 @@ def execute(params: dict, x, cfg, *, in_dim: int | None = None,
                 epilogue=epilogue, bias=bias, residual=residual, fuse=fuse)
         # a sharded plan without a live mesh (explicit override outside
         # sharding.use): fall through and run unsharded on local math
-    mark = f"gemm.{be.name}.m{m}.k{k}.b{batch}"
-    # mode/d/sb make the series self-describing for the perf-model
-    # regression sentinel (obs.perfmodel.samples_from_snapshot)
-    labels = {"backend": be.name, "m": m, "k": k, "b": batch,
-              "mode": spec.mode, "d": d, "sb": spec.scale_block}
-    x = obs.jit_begin(x, mark)
     if fuse:
-        y = be.run(spec, p, params, x, k=k, precision=precision,
-                   epilogue=epilogue, bias=bias, residual=residual)
-        return obs.jit_end(y, mark, cat="gemm", hist="kernel_gemm_s",
-                           hist_labels=labels)
+        return be.run(spec, p, params, x, k=k, precision=precision,
+                      epilogue=epilogue, bias=bias, residual=residual)
     y = be.run(spec, p, params, x, k=k, precision=precision)
-    y = obs.jit_end(y, mark, cat="gemm", hist="kernel_gemm_s",
-                    hist_labels=labels)
     return apply_epilogue(y, epilogue, bias=bias, residual=residual)

@@ -414,8 +414,8 @@ def autotune(spec: QuantSpec, m: int, k: int, batch: int, backend: str, *,
                         help="candidates skipped by model-guided search",
                         backend=backend).inc(pruned)
     params, x = _synthetic_call(spec, d, m, k, batch)
-    with obs.tracer().span("autotune", cat="dispatch", key=key,
-                           candidates=len(cands), model_pruned=pruned):
+    with obs.tracer().span("autotune", key=key, candidates=len(cands),
+                           model_pruned=pruned):
         timed = [(_time_plan(be, spec, p, params, x, k, reps), i, p)
                  for i, p in enumerate(cands)]
     best_s, best_i, winner = min(timed)
@@ -548,8 +548,8 @@ def tune_shard_variants(spec: QuantSpec, m: int, k: int, batch: int,
     lb = batch // shard.axis_size(shard.batch)
     elems = m * lb
     rows = []
-    with obs.tracer().span("autotune.shard_variants", cat="dispatch",
-                           key=base_key, candidates=len(cands)):
+    with obs.tracer().span("autotune.shard_variants", key=base_key,
+                           candidates=len(cands)):
         for pc, impl in cands:
             cand = dataclasses.replace(shard, pipeline_chunks=pc,
                                        collective_impl=impl)

@@ -351,16 +351,6 @@ def run_sharded(backend, spec, plan, params: dict, x, *, k: int, mesh,
         in_specs["residual"] = P(*((s.batch,) + mid + (out_m,)))
     out_specs = P(*((s.batch,) + mid + (out_m,)))
 
-    # trace attribution: compute vs contraction collective, named by the
-    # shard layout so a mesh trace splits step time between them.  The
-    # marks are keyed to the *output* of each stage (data dependency, no
-    # ordered side channel — safe under shard_map), and fire once per
-    # device shard (per chunk when pipelined — the span overlap between
-    # the two families is the measured comms/compute overlap).
-    tagname = s.tag()
-    mk_compute = f"shard.compute.{tagname}.k{k_chunk}"
-    mk_coll = f"shard.collective.{s.collective}.{tagname}"
-
     def contract(y):
         """Resolve k-sharded partials with the planned collective."""
         n = size[s.k]
@@ -376,44 +366,23 @@ def run_sharded(backend, spec, plan, params: dict, x, *, k: int, mesh,
         return jax.lax.psum(y, s.k)
 
     def compute_chunk(p_c, x_c):
-        x_c = obs.jit_begin(x_c, mk_compute)
-        y = backend.run(spec, inner_plan, p_c, x_c, k=k_chunk,
-                        precision=precision)
-        return obs.jit_end(y, mk_compute, cat="shard",
-                           hist="shard_compute_s",
-                           hist_labels={"tag": tagname})
-
-    def collect_chunk(y):
-        y = obs.jit_begin(y, mk_coll)
-        y = contract(y)
-        return obs.jit_end(y, mk_coll, cat="shard",
-                           hist="shard_collective_s",
-                           hist_labels={"collective": s.collective,
-                                        "axis": s.k,
-                                        "impl": s.collective_impl})
+        return backend.run(spec, inner_plan, p_c, x_c, k=k_chunk,
+                           precision=precision)
 
     def local(ops):
         b_l, r_l = ops.get("bias"), ops.get("residual")
         if s.k is None:
-            x_l = obs.jit_begin(ops["x"], mk_compute)
             if fuse:
-                y = backend.run(spec, inner_plan, ops["params"], x_l,
-                                k=k_local, precision=precision,
-                                epilogue=epilogue, bias=b_l, residual=r_l)
-                return obs.jit_end(y, mk_compute, cat="shard",
-                                   hist="shard_compute_s",
-                                   hist_labels={"tag": tagname})
-            y = backend.run(spec, inner_plan, ops["params"], x_l,
+                return backend.run(spec, inner_plan, ops["params"],
+                                   ops["x"], k=k_local, precision=precision,
+                                   epilogue=epilogue, bias=b_l, residual=r_l)
+            y = backend.run(spec, inner_plan, ops["params"], ops["x"],
                             k=k_local, precision=precision)
-            y = obs.jit_end(y, mk_compute, cat="shard",
-                            hist="shard_compute_s",
-                            hist_labels={"tag": tagname})
             return apply_epilogue(y, epilogue, bias=b_l, residual=r_l)
         # row-parallel: partial sums over the local k slice; the epilogue
         # must see the *resolved* sum, never the per-shard partials
         if pc == 1:
-            y = compute_chunk(ops["params"], ops["x"])
-            y = collect_chunk(y)
+            y = contract(compute_chunk(ops["params"], ops["x"]))
             return apply_epilogue(y, epilogue, bias=b_l, residual=r_l)
         d_pack = 1 if spec.mode == "bf16" else int(spec.d)
         sb_pack = 1 if spec.mode == "bf16" else int(spec.scale_block)
@@ -429,7 +398,7 @@ def run_sharded(backend, spec, plan, params: dict, x, *, k: int, mesh,
                 # compute is issued — the in-flight ring and the compute
                 # above share no dataflow, so the scheduler overlaps them
                 out = pending if out is None else out + pending
-            pending = collect_chunk(y_c)
+            pending = contract(y_c)
         y = pending if out is None else out + pending
         return apply_epilogue(y, epilogue, bias=b_l, residual=r_l)
 

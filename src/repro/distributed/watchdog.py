@@ -60,8 +60,6 @@ class Watchdog:
             "watchdog_hangs_total",
             help="hang-timer firings (step exceeded hang_factor x mean)"
         ).inc()
-        obs.tracer().instant("watchdog.hang", cat="watchdog",
-                             hang_count=self.hang_count)
         if self.on_hang:
             self.on_hang()
 
@@ -80,8 +78,6 @@ class Watchdog:
                     "watchdog_stragglers_total",
                     help="steps whose z-score exceeded the threshold"
                 ).inc()
-                obs.tracer().instant("watchdog.straggler", cat="watchdog",
-                                     step_time=dt, mean=mean, std=std)
                 if self.on_straggler:
                     self.on_straggler(dt, mean, std)
         self._times.append(dt)

@@ -30,7 +30,6 @@ from typing import NamedTuple
 
 import jax.numpy as jnp
 
-from repro import obs
 from repro.dispatch import registry
 from repro.distributed.sharding import active_mesh, constrain
 from repro.kvq.quantize import kv_dequantize
@@ -61,13 +60,10 @@ def run_jnp(spec: KVQuantSpec, cfg, q, pool, view_slots, positions, *,
     vc = pool["v"].reshape(nb * bs, hk, dhp)
     ks = pool["k_scale"].reshape(nb * bs, hk)
     vs = pool["v_scale"].reshape(nb * bs, hk)
-    kc = obs.jit_begin(kc, "kv_dequant")
     k_view = kv_dequantize(jnp.take(kc, view_slots, axis=0),
                            jnp.take(ks, view_slots, axis=0), spec, dh)
     v_view = kv_dequantize(jnp.take(vc, view_slots, axis=0),
                            jnp.take(vs, view_slots, axis=0), spec, dh)
-    v_view = obs.jit_end(v_view, "kv_dequant", cat="kv",
-                         hist="kv_dequant_s")
     k_view = constrain(k_view, "batch", "kv_seq", "kvheads", "head_dim")
     v_view = constrain(v_view, "batch", "kv_seq", "kvheads", "head_dim")
     m = layers.view_mask(view_slots.shape[1], positions, window=window)

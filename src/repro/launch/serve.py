@@ -27,6 +27,7 @@ produce — see repro.dispatch.shard).  On a CPU host add
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import sys
 import time
@@ -54,35 +55,6 @@ def build_model(args):
     if args.quant != "bf16":
         print(f"[serve] quantized weights to {args.quant} (d={args.d})")
     return params, cfg, key
-
-
-def check_run_regressions(args) -> None:
-    """Run the perf-model regression sentinel over this run's measured
-    ``kernel_gemm_s`` series (obs.perfmodel).  SystemExit(1) when any
-    kernel ran slower than the tolerance band allows; a missing or
-    mismatched calibration skips with a note (a fresh machine should
-    serve, not crash — CI pins a calibration and relies on the exit
-    code)."""
-    from repro.obs import perfmodel as pm
-
-    cal = pm.load_calibration(args.calibration)
-    if cal is None:
-        path = args.calibration or pm.default_calibration_path()
-        print(f"[serve] check-regressions: no calibration matching this "
-              f"device/interpret partition at {path}; skipped "
-              f"(python -m repro.obs --calibrate)", file=sys.stderr)
-        return
-    samples = pm.samples_from_registry()
-    report = pm.check_regressions(samples, cal)
-    print(pm.render_report(report))
-    if not report["n_samples"]:
-        print("[serve] check-regressions: no kernel_gemm_s samples "
-              "recorded (is tracing on?)", file=sys.stderr)
-    elif not report["ok"]:
-        raise SystemExit(
-            f"[serve] check-regressions: {report['n_outliers']} kernel "
-            f"timing(s) exceeded {report['tolerance']:g}x the model "
-            f"prediction")
 
 
 def exec_policy(args) -> dispatch.ExecPolicy | None:
@@ -274,6 +246,26 @@ def run_continuous(args, params, cfg, mesh=None):
     return results
 
 
+def serve(args, mesh):
+    """Build the model and run the engine the CLI asked for."""
+    params, cfg, key = build_model(args)
+    if args.engine == "continuous":
+        return run_continuous(args, params, cfg, mesh)
+    if args.kv_bits != 16 or args.kv_pool_mib:
+        print("[serve] --kv-bits/--kv-pool-mib apply to the paged "
+              "pool only; ignored by --engine static", file=sys.stderr)
+    if args.autotune_cache is not None:
+        dispatch.set_cache_path(args.autotune_cache)
+    if mesh is None:
+        with dispatch.using_policy(exec_policy(args)):
+            return run_static(args, params, cfg, key)
+    params = jax.device_put(params,
+                            shd.shardings(params, mesh, args.mesh_rules))
+    with shd.use(mesh, args.mesh_rules), \
+            dispatch.using_policy(exec_policy(args)):
+        return run_static(args, params, cfg, key)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -373,21 +365,14 @@ def main(argv=None):
     ap.add_argument("--metrics-json", default=None, metavar="PATH",
                     help="write a versioned registry snapshot "
                          "(obs.metrics) on exit")
-    ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="enable tracing and write Chrome-trace JSON "
-                         "(load at https://ui.perfetto.dev) on exit")
+    ap.add_argument("--trace-out", default=None, metavar="DIR",
+                    help="write a jax.profiler trace of the run under DIR "
+                         "(device ops with their model scopes and the "
+                         "engine spans on one clock; a Perfetto file "
+                         "beside the .xplane.pb)")
     ap.add_argument("--prom-port", type=int, default=0,
                     help="expose /metrics in Prometheus text format on "
                          "this port for the lifetime of the run")
-    ap.add_argument("--check-regressions", action="store_true",
-                    help="after the run, compare measured kernel times "
-                         "against the calibrated perf model "
-                         "(obs.perfmodel); exit 1 on outliers — implies "
-                         "tracing so kernel timings are recorded")
-    ap.add_argument("--calibration", default=None, metavar="PATH",
-                    help="perf-model calibration.json for "
-                         "--check-regressions (default: "
-                         "$REPRO_CALIBRATION or the user cache dir)")
     args = ap.parse_args(argv)
 
     from repro.launch.cache import use_compile_cache
@@ -412,50 +397,21 @@ def main(argv=None):
             print(f"[serve] fault injection armed from env: "
                   f"{plan.describe()}")
 
-    # tracing must be on BEFORE the engine builds/compiles: jit marks are
-    # staged at trace time, so a later enable would record host spans but
-    # no in-graph gemm/collective events
-    if args.trace_out or args.check_regressions:
-        # the sentinel reads kernel_gemm_s series, which only exist when
-        # the in-graph jit marks were staged at trace time
-        obs.enable_tracing(clear=True)
     prom = None
     if args.prom_port:
         prom = obs.serve_prometheus(args.prom_port)
         print(f"[serve] prometheus /metrics on port "
               f"{prom.server_address[1]}")
 
+    profile = (jax.profiler.trace(args.trace_out,
+                                  create_perfetto_trace=True)
+               if args.trace_out else contextlib.nullcontext())
     try:
-        params, cfg, key = build_model(args)
-        if args.engine == "continuous":
-            out = run_continuous(args, params, cfg, mesh)
-        else:
-            if args.kv_bits != 16 or args.kv_pool_mib:
-                print("[serve] --kv-bits/--kv-pool-mib apply to the paged "
-                      "pool only; ignored by --engine static",
-                      file=sys.stderr)
-            if args.autotune_cache is not None:
-                dispatch.set_cache_path(args.autotune_cache)
-            if mesh is not None:
-                params = jax.device_put(
-                    params, shd.shardings(params, mesh, args.mesh_rules))
-                with shd.use(mesh, args.mesh_rules), \
-                        dispatch.using_policy(exec_policy(args)):
-                    out = run_static(args, params, cfg, key)
-            else:
-                with dispatch.using_policy(exec_policy(args)):
-                    out = run_static(args, params, cfg, key)
-        if args.check_regressions:
-            jax.effects_barrier()  # flush kernel timing callbacks
-            check_run_regressions(args)
-        return out
+        with profile:
+            return serve(args, mesh)
     finally:
         if args.trace_out:
-            jax.effects_barrier()  # flush in-flight debug callbacks
-            obs.tracer().save(args.trace_out)
-            obs.disable_tracing()
-            print(f"[serve] wrote trace {args.trace_out} "
-                  f"({len(obs.tracer().events())} events)")
+            print(f"[serve] wrote profiler trace under {args.trace_out}")
         if args.metrics_json:
             snap = obs.registry().snapshot(extra={
                 "arch": args.arch, "quant": args.quant,

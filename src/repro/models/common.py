@@ -26,16 +26,17 @@ def norm_init(d: int, kind: str) -> dict:
 
 def norm_apply(p: dict, x: jnp.ndarray, kind: str, *, rms_offset: bool = False,
                eps: float = 1e-6) -> jnp.ndarray:
-    xf = x.astype(jnp.float32)
-    if kind == "rmsnorm":
-        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-        y = xf * jax.lax.rsqrt(var + eps)
-        w = (1.0 + p["scale"]) if rms_offset else p["scale"]
-        return (y * w).astype(x.dtype)
-    mean = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.var(xf, axis=-1, keepdims=True)
-    y = (xf - mean) * jax.lax.rsqrt(var + eps)
-    return (y * p["scale"] + p["bias"]).astype(x.dtype)
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        if kind == "rmsnorm":
+            var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+            y = xf * jax.lax.rsqrt(var + eps)
+            w = (1.0 + p["scale"]) if rms_offset else p["scale"]
+            return (y * w).astype(x.dtype)
+        mean = jnp.mean(xf, axis=-1, keepdims=True)
+        var = jnp.var(xf, axis=-1, keepdims=True)
+        y = (xf - mean) * jax.lax.rsqrt(var + eps)
+        return (y * p["scale"] + p["bias"]).astype(x.dtype)
 
 
 # ---------------------------------------------------------------- linears
@@ -64,17 +65,25 @@ def linear_apply(p, x, quant=qlinear.DENSE, *, in_dim=None, tag=None,
     on every other backend (identical at f32 activations) — so model
     code stops issuing separate element-wise HBM passes after its
     quantized matmuls.  Under a contraction-sharded (row-parallel) plan
-    the tail instead runs exactly once after the psum/reduce-scatter."""
+    the tail instead runs exactly once after the psum/reduce-scatter.
+
+    A tagged linear's ops sit under the ``linear.<tag>`` scope (op
+    metadata only), so a profiler trace names each role's device time."""
     ep = None
     if act != "none" or bias is not None or residual is not None \
             or out_dtype is not None:
         ep = Epilogue(act=act, bias=bias is not None,
                       residual=residual is not None, out_dtype=out_dtype)
-    if shard_axes is None and tag is not None:
+    if tag is None:
+        return qlinear.apply(p, x, quant, in_dim=in_dim, epilogue=ep,
+                             bias=bias, residual=residual,
+                             shard_axes=shard_axes)
+    if shard_axes is None:
         shard_axes = shd_rules.LINEAR_AXES.get(tag)
-    return qlinear.apply(p, x, quant, in_dim=in_dim, tag=tag, epilogue=ep,
-                         bias=bias, residual=residual,
-                         shard_axes=shard_axes)
+    with jax.named_scope(f"linear.{tag}"):
+        return qlinear.apply(p, x, quant, in_dim=in_dim, tag=tag,
+                             epilogue=ep, bias=bias, residual=residual,
+                             shard_axes=shard_axes)
 
 
 def softcap(x: jnp.ndarray, cap: float) -> jnp.ndarray:

@@ -221,20 +221,24 @@ def attn_paged(p, cfg, x, cache, positions, write_slots, view_slots,
         kp = k_pool.reshape(nb * bs, hk, dh)
         vp = v_pool.reshape(nb * bs, hk, dh)
         ws = write_slots.reshape(-1)
-        kp = kp.at[ws].set(k.reshape(-1, hk, dh).astype(kp.dtype))
-        vp = vp.at[ws].set(v.reshape(-1, hk, dh).astype(vp.dtype))
+        with jax.named_scope("attn.kv_write"):
+            kp = kp.at[ws].set(k.reshape(-1, hk, dh).astype(kp.dtype))
+            vp = vp.at[ws].set(v.reshape(-1, hk, dh).astype(vp.dtype))
         # mesh-aware pool layout: slots replicated (every data shard must
         # resolve any sequence's blocks), kvheads on the model axis when
         # divisible — matching runtime.serve.init_paged_cache's placement
         # so the scatter/gather pair stays local to each model shard
         kp = constrain(kp, "none", "kvheads", "head_dim")
         vp = constrain(vp, "none", "kvheads", "head_dim")
-        k_view = jnp.take(kp, view_slots, axis=0)  # (B, W, Hk, Dh)
-        v_view = jnp.take(vp, view_slots, axis=0)
-        k_view = constrain(k_view, "batch", "kv_seq", "kvheads", "head_dim")
-        v_view = constrain(v_view, "batch", "kv_seq", "kvheads", "head_dim")
-        m = view_mask(view_slots.shape[1], positions, window=window)
-        out = _sdpa(cfg, q, k_view, v_view, m[:, None])
+        with jax.named_scope("attn.core"):
+            k_view = jnp.take(kp, view_slots, axis=0)  # (B, W, Hk, Dh)
+            v_view = jnp.take(vp, view_slots, axis=0)
+            k_view = constrain(k_view, "batch", "kv_seq", "kvheads",
+                               "head_dim")
+            v_view = constrain(v_view, "batch", "kv_seq", "kvheads",
+                               "head_dim")
+            m = view_mask(view_slots.shape[1], positions, window=window)
+            out = _sdpa(cfg, q, k_view, v_view, m[:, None])
         new_cache = dict(cache,
                          k=kp.reshape(nb, bs, hk, dh),
                          v=vp.reshape(nb, bs, hk, dh))
@@ -263,14 +267,16 @@ def _attn_paged_quantized(cfg, q, k, v, cache, positions, write_slots,
     for name, codes, scales in (("k", kq, ks), ("v", vq, vs)):
         cp = cache[name].reshape(nb * bs, hk, dhp)
         sp = cache[f"{name}_scale"].reshape(nb * bs, hk)
-        cp = cp.at[ws].set(codes.reshape(-1, hk, dhp))
-        sp = sp.at[ws].set(scales.reshape(-1, hk))
+        with jax.named_scope("attn.kv_write"):
+            cp = cp.at[ws].set(codes.reshape(-1, hk, dhp))
+            sp = sp.at[ws].set(scales.reshape(-1, hk))
         cp = constrain(cp, "none", "kvheads", "none")
         sp = constrain(sp, "none", "kvheads")
         new_cache[name] = cp.reshape(nb, bs, hk, dhp)
         new_cache[f"{name}_scale"] = sp.reshape(nb, bs, hk)
-    out = kvq_attn.run(spec, cfg, q, new_cache, view_slots, positions,
-                       window=window)
+    with jax.named_scope("attn.core"):
+        out = kvq_attn.run(spec, cfg, q, new_cache, view_slots, positions,
+                           window=window)
     return out, new_cache
 
 

@@ -274,12 +274,14 @@ def _sinusoidal(S: int, d: int) -> jnp.ndarray:
 
 def embed_inputs(params, cfg: ModelConfig, tokens, *, patch_embeds=None):
     """tokens (B, S_text); vlm: patch embeds are prepended (stub frontend)."""
-    x = jnp.take(params["embedding"], tokens, axis=0)
-    if cfg.embed_scale:
-        x = x * cfg.d_model**0.5
-    if patch_embeds is not None:
-        x = jnp.concatenate([patch_embeds.astype(x.dtype), x], axis=1)
-    return constrain(x.astype(jnp.dtype(cfg.dtype)), "batch", "seq", "embed")
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embedding"], tokens, axis=0)
+        if cfg.embed_scale:
+            x = x * cfg.d_model**0.5
+        if patch_embeds is not None:
+            x = jnp.concatenate([patch_embeds.astype(x.dtype), x], axis=1)
+        return constrain(x.astype(jnp.dtype(cfg.dtype)), "batch", "seq",
+                         "embed")
 
 
 def encode(params, cfg: ModelConfig, frames) -> jnp.ndarray:

@@ -1,4 +1,4 @@
-"""Observability layer: metrics registry, structured tracer, cost model.
+"""Observability layer: metrics registry, span tracer, cost model.
 
 One import surface for the rest of the repo::
 
@@ -6,16 +6,13 @@ One import surface for the rest of the repo::
 
     obs.registry().counter("dispatch_plan_cache_total",
                            result="hit").inc()
-    with obs.tracer().span("engine.step", cat="serving"):
+    with obs.tracer().span("engine.fetch"):
         ...
-    y = obs.jit_end(backend.run(...), "gemm", cat="dispatch")
 
-Everything is off-by-default and near-free when off: counters are
-attribute bumps, ``tracer().span`` returns a shared no-op context
-manager, and :func:`jit_begin`/:func:`jit_end` stage **nothing** into
-jitted code unless tracing was enabled at trace time (see
-``obs.trace`` for the contract and ``tests/test_obs.py`` for the
-zero-overhead assertions).
+Counters are attribute bumps; a span is a profiler annotation plus one
+ring append (``obs.trace``), always on, and nothing is ever staged into
+jitted code.  Importing this package registers the listener that counts
+backend compiles into ``jax_compiles_total``.
 """
 
 from repro.obs import costs  # noqa: F401  (re-export module)
@@ -38,23 +35,17 @@ from repro.obs.metrics import (  # noqa: F401
     validate_snapshot_file,
 )
 from repro.obs.trace import (  # noqa: F401
-    TRACE_SCHEMA_VERSION,
+    RING_SPANS,
+    Span,
     Tracer,
-    disable_tracing,
-    enable_tracing,
-    jit_begin,
-    jit_end,
+    compiles,
     tracer,
-    validate_trace,
-    validate_trace_file,
 )
 
 __all__ = [
     "Registry", "registry", "serve_prometheus",
     "validate_snapshot", "validate_snapshot_file",
     "SNAPSHOT_SCHEMA_VERSION",
-    "Tracer", "tracer", "enable_tracing", "disable_tracing",
-    "jit_begin", "jit_end",
-    "validate_trace", "validate_trace_file", "TRACE_SCHEMA_VERSION",
+    "Span", "Tracer", "tracer", "compiles", "RING_SPANS",
     "costs", "perfmodel",
 ]

@@ -2,7 +2,6 @@
 measured-vs-predicted regression sentinel:
 
     python -m repro.obs --validate-snapshot metrics.json
-    python -m repro.obs --validate-trace trace.json
     python -m repro.obs --calibrate --bench benchmarks/results/BENCH_kernels.json \
         --calibration calibration.json
     python -m repro.obs --validate-calibration calibration.json
@@ -11,9 +10,9 @@ measured-vs-predicted regression sentinel:
 
 ``--calibrate`` fits the analytic perf-model constants (obs.perfmodel)
 from whichever measurement sources are given (``--plan-cache`` autotune
-timings, ``--bench`` BENCH_kernels.json, ``--metrics`` serve-run
-snapshots; the plan cache at its default path is used when no source is
-named) and writes a versioned calibration.json.
+timings, ``--bench`` BENCH_kernels.json; the plan cache at its default
+path is used when no source is named) and writes a versioned
+calibration.json.
 
 ``--check-regressions`` re-reads the same sources and fails (exit 1)
 when any measured timing exceeds ``--tolerance`` x the model's
@@ -27,11 +26,10 @@ line otherwise.
 from __future__ import annotations
 
 import argparse
-import json
 import sys
 from pathlib import Path
 
-from repro.obs import validate_snapshot_file, validate_trace_file
+from repro.obs import validate_snapshot_file
 from repro.obs import perfmodel as pm
 
 
@@ -40,7 +38,7 @@ def _gather_samples(args) -> tuple[list, list]:
     samples: list = []
     sources: list = []
     plan_caches = list(args.plan_cache)
-    if not plan_caches and not args.bench and not args.metrics:
+    if not plan_caches and not args.bench:
         plan_caches = [None]  # default: the process plan cache
     for p in plan_caches:
         got, untagged = pm.samples_from_plan_cache(p)
@@ -53,10 +51,6 @@ def _gather_samples(args) -> tuple[list, list]:
     for p in args.bench:
         samples += pm.samples_from_bench(p)
         sources.append(f"bench:{p}")
-    for p in args.metrics:
-        doc = json.loads(Path(p).read_text())
-        samples += pm.samples_from_snapshot(doc)
-        sources.append(f"metrics:{p}")
     return samples, sources
 
 
@@ -64,8 +58,6 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="python -m repro.obs")
     ap.add_argument("--validate-snapshot", action="append", default=[],
                     metavar="PATH", help="metrics snapshot JSON to check")
-    ap.add_argument("--validate-trace", action="append", default=[],
-                    metavar="PATH", help="Chrome-trace JSON to check")
     ap.add_argument("--validate-calibration", action="append", default=[],
                     metavar="PATH", help="perf-model calibration to check")
     ap.add_argument("--calibrate", action="store_true",
@@ -79,9 +71,6 @@ def main(argv=None) -> int:
                                          "timings (measurement source)")
     ap.add_argument("--bench", action="append", default=[], metavar="PATH",
                     help="BENCH_kernels.json (measurement source)")
-    ap.add_argument("--metrics", action="append", default=[],
-                    metavar="PATH", help="metrics snapshot with "
-                                         "kernel_gemm_s series (source)")
     ap.add_argument("--calibration", default=None, metavar="PATH",
                     help="calibration.json path (default: "
                          "$REPRO_CALIBRATION or the user cache dir)")
@@ -92,17 +81,14 @@ def main(argv=None) -> int:
     ap.add_argument("--report-out", default=None, metavar="PATH",
                     help="write the ranked regression report (markdown)")
     args = ap.parse_args(argv)
-    actions = (args.validate_snapshot or args.validate_trace
-               or args.validate_calibration or args.calibrate
-               or args.check_regressions)
+    actions = (args.validate_snapshot or args.validate_calibration
+               or args.calibrate or args.check_regressions)
     if not actions:
         ap.error("nothing to do")
 
     problems: list[str] = []
     for p in args.validate_snapshot:
         problems += [f"{p}: {e}" for e in validate_snapshot_file(p)]
-    for p in args.validate_trace:
-        problems += [f"{p}: {e}" for e in validate_trace_file(p)]
     for p in args.validate_calibration:
         problems += [f"{p}: {e}" for e in pm.validate_calibration_file(p)]
 
@@ -165,8 +151,7 @@ def main(argv=None) -> int:
     if problems:
         print("\n".join(problems), file=sys.stderr)
         return 1
-    n = (len(args.validate_snapshot) + len(args.validate_trace)
-         + len(args.validate_calibration))
+    n = len(args.validate_snapshot) + len(args.validate_calibration)
     if n:
         print(f"ok: {n} artifact(s) schema-valid")
     return 0

@@ -12,8 +12,7 @@ Design constraints, in order:
 * **Near-zero overhead.**  Recording is a Python attribute bump under
   the GIL — no locks on the hot path beyond histogram reservoir
   appends, no formatting until export.  Nothing here ever stages work
-  into a jit trace (that is ``obs.trace``'s job, and only when tracing
-  is explicitly on).
+  into a jit trace.
 * **Accurate serving percentiles.**  Histograms keep a bounded
   reservoir of raw samples (default 8192) next to fixed buckets, so
   p50/p95/p99 in snapshots are computed from real samples instead of

@@ -15,11 +15,10 @@ throughput from measured machine constants:
 
 The five constants are **calibrated** by weighted least squares from
 timings the stack already persists — the autotuner's per-candidate
-``timings`` tables in the plan cache, ``BENCH_kernels.json`` rows, and
-``kernel_gemm_s`` histograms from a traced serve run — and stored as a
-versioned ``calibration.json`` artifact.  The fit minimizes *relative*
-error (each row is scaled by 1/measured), so microsecond decode shapes
-weigh the same as millisecond prefill shapes.
+``timings`` tables in the plan cache and ``BENCH_kernels.json`` rows —
+and stored as a versioned ``calibration.json`` artifact.  The fit
+minimizes *relative* error (each row is scaled by 1/measured), so
+microsecond decode shapes weigh the same as millisecond prefill shapes.
 
 Calibrations are partitioned on (device, interpret): an interpret-mode
 CPU fit is never used to predict compiled TPU kernels and vice versa
@@ -751,46 +750,6 @@ def samples_from_bench(path: str | os.PathLike) -> list[Sample]:
                               acc_in_vmem=False,
                               source=f"bench:{r['shape']}:legacy"))
     return out
-
-
-def samples_from_snapshot(doc: dict, *, device: str | None = None,
-                          interpret: bool | None = None) -> list[Sample]:
-    """Samples from ``kernel_gemm_s`` histograms in a metrics snapshot
-    (a serve run with tracing on).  Measured = p50 of the series; the
-    plan is the shape heuristic (serving resolves heuristic-or-tuned
-    plans, so p50 under the heuristic tiles is the honest comparison).
-    Histograms whose labels predate the mode/d tags are skipped."""
-    if device is None or interpret is None:
-        dev, itp = current_partition()
-        device = device if device is not None else dev
-        interpret = interpret if interpret is not None else itp
-    out: list[Sample] = []
-    for row in doc.get("histograms", []):
-        if row.get("name") != "kernel_gemm_s" or not row.get("count"):
-            continue
-        lb = row.get("labels", {})
-        if not {"backend", "m", "k", "b", "mode", "d", "sb"} <= set(lb):
-            continue
-        p50 = row.get("p50")
-        if not p50:
-            continue
-        out.append(Sample(
-            backend=str(lb["backend"]), mode=str(lb["mode"]),
-            d=int(lb["d"]), scale_block=int(lb["sb"]), m=int(lb["m"]),
-            k=int(lb["k"]), b=int(lb["b"]), measured_s=float(p50),
-            device=device, interpret=bool(interpret),
-            source=(f"serve:kernel_gemm_s:{lb['backend']}"
-                    f".m{lb['m']}.k{lb['k']}.b{lb['b']}")))
-    return out
-
-
-def samples_from_registry(reg=None) -> list[Sample]:
-    """Live-registry variant of :func:`samples_from_snapshot` (the
-    ``serve --check-regressions`` path)."""
-    from repro import obs
-
-    reg = reg or obs.registry()
-    return samples_from_snapshot(reg.snapshot())
 
 
 # =====================================================================

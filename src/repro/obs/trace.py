@@ -1,189 +1,141 @@
-"""Structured span/event tracer emitting Chrome-trace (Perfetto) JSON.
+"""Host spans on the profiler's clock, kept in a bounded ring.
 
-Two recording surfaces share one event buffer:
+``with tracer().span("engine.fetch"): ...`` opens a
+``jax.profiler.TraceAnnotation`` of that name, so any profiler trace
+(``jax.profiler.trace``, ``serve --trace-out``) holds the span on the
+same clock and timeline as the device ops.  On exit the span is also
+appended to an in-memory ring of the last :data:`RING_SPANS` spans, with
+its ``time.perf_counter`` bounds, its enclosing span (``parent``, so self
+time can be computed) and its args; :meth:`Tracer.spans` reads an
+interval of it back.
 
-* **Host spans** — ``with tracer().span("engine.step"): ...`` around
-  ordinary Python (the engine loop, the scheduler, benchmarks).  These
-  are complete ("ph": "X") events with microsecond timestamps.
-* **Jit marks** — :func:`jit_begin` / :func:`jit_end` stage a
-  ``jax.debug.callback`` into the *current trace* whose firing is
-  ordered by data dependency: the begin-mark depends on the kernel's
-  input (fires when the input is ready ≈ compute start) and the
-  end-mark on its output (fires when the result materializes ≈ compute
-  end).  The host side pairs them by name into "X" events, so a jitted
-  serving step yields per-linear GeMM and per-collective spans inside
-  the same trace as the engine's host spans.
+Recording is always on and costs a few ``perf_counter`` calls, one
+TraceMe and one ``deque.append`` per span.  Nothing is ever staged into
+jitted code: device-side names come from ``jax.named_scope`` in the
+model code, which only touches op metadata.
 
-**Zero overhead when disabled** is a hard contract: ``tracer().enabled``
-is checked at *trace time* (plain Python), so with tracing off not a
-single callback is staged into the jitted computation — the lowered HLO
-is byte-identical to a build without obs.  ``jit_marks_staged`` counts
-staged marks so tests can assert exactly that.  Consequence: enable
-tracing *before* building/compiling the thing you want traced;
-already-compiled executables keep whatever was staged when they traced.
-
-Load the written file at https://ui.perfetto.dev (or
-chrome://tracing) — README §Observability.
+``jax_compiles_total`` counts backend compiles, from one
+``jax.monitoring`` listener registered on import.
 """
 
 from __future__ import annotations
 
-import json
-import os
+import collections
+import math
 import threading
 import time
 from contextlib import AbstractContextManager
 
-TRACE_SCHEMA_VERSION = 1
+import jax
 
-# observability-of-the-observability: how many jit marks were staged
-# into traces since import (tests assert 0 on the tracing-off path)
-jit_marks_staged = 0
+from repro.obs import metrics as M
 
-# Perfetto lane ids: host-side spans vs events fired from jax callback
-# threads (kept separate so reordered callback arrivals cannot corrupt
-# the host lane's nesting)
-TID_HOST = 0
-TID_JIT = 1
+# a 51 s window of >= 5 ms iterations at ~7 spans each fits
+RING_SPANS = 65_536
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
-class _NullSpan(AbstractContextManager):
-    __slots__ = ()
+class Span:
+    """One closed span: ``t0``/``t1`` on ``time.perf_counter``,
+    ``parent`` the enclosing :class:`Span` (None at the top)."""
 
-    def __exit__(self, *exc):
-        return False
+    __slots__ = ("name", "t0", "t1", "parent", "args")
 
-
-_NULL_SPAN = _NullSpan()
-
-
-class _Span(AbstractContextManager):
-    __slots__ = ("tracer", "name", "cat", "args", "t0")
-
-    def __init__(self, tracer, name, cat, args):
-        self.tracer = tracer
+    def __init__(self, name: str, parent: "Span | None", args: dict):
         self.name = name
-        self.cat = cat
+        self.parent = parent
         self.args = args
+        self.t0 = self.t1 = 0.0
 
-    def __enter__(self):
-        self.t0 = time.perf_counter()
-        return self
+    @property
+    def duration(self) -> float:
+        return self.t1 - self.t0
+
+    def __repr__(self):
+        return (f"Span({self.name!r}, {self.t0:.6f}, {self.t1:.6f}, "
+                f"parent={getattr(self.parent, 'name', None)!r}, "
+                f"args={self.args!r})")
+
+
+class _Open(AbstractContextManager):
+    """An open span: the profiler annotation plus the ring record.  The
+    ``as`` target is the :class:`Span`, whose ``args`` may still grow
+    before exit (e.g. ``compiled=True``)."""
+
+    __slots__ = ("tracer", "span", "me")
+
+    def __init__(self, tracer: "Tracer", span: Span, me):
+        self.tracer = tracer
+        self.span = span
+        self.me = me
+
+    def __enter__(self) -> Span:
+        self.tracer._stack().append(self.span)
+        self.me.__enter__()
+        self.span.t0 = time.perf_counter()
+        return self.span
 
     def __exit__(self, *exc):
-        self.tracer._complete(self.name, self.cat, self.t0,
-                              time.perf_counter(), self.args, TID_HOST)
+        span = self.span
+        span.t1 = time.perf_counter()
+        if span.args:
+            self.me.set_metadata(**span.args)
+        self.me.__exit__(*exc)
+        self.tracer._stack().pop()
+        self.tracer._append(span)
         return False
 
 
 class Tracer:
-    def __init__(self):
-        self.enabled = False
-        self._events: list[dict] = []
-        self._t0 = time.perf_counter()
+    def __init__(self, maxlen: int = RING_SPANS):
+        self._ring: collections.deque = collections.deque(maxlen=maxlen)
+        self._local = threading.local()
         self._lock = threading.Lock()
-        self._open: dict[str, list[float]] = {}  # jit-mark pairing stacks
-        self._pid = os.getpid()
+        self._held_from = -math.inf
 
-    # ----------------------------------------------------------- control
-    def enable(self, *, clear: bool = False) -> None:
-        if clear:
-            self.clear()
-        self.enabled = True
+    def _stack(self) -> list:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
 
-    def disable(self) -> None:
-        self.enabled = False
+    def _open(self, name: str, args: dict, me) -> _Open:
+        stack = self._stack()
+        return _Open(self, Span(name, stack[-1] if stack else None, args),
+                     me)
 
-    def clear(self) -> None:
+    def _append(self, span: Span) -> None:
+        ring = self._ring
         with self._lock:
-            self._events.clear()
-            self._open.clear()
-        self._t0 = time.perf_counter()
+            if len(ring) == ring.maxlen:
+                # every dropped span ended at or before this
+                self._held_from = max(self._held_from, ring[0].t1)
+            ring.append(span)
 
-    # ----------------------------------------------------------- record
-    def _us(self, t: float) -> float:
-        return (t - self._t0) * 1e6
+    def span(self, name: str, **args) -> _Open:
+        """Context manager recording one span; ``args`` become the
+        annotation's metadata and the ring record's ``args``."""
+        return self._open(name, args,
+                          jax.profiler.TraceAnnotation(name))
 
-    def _complete(self, name, cat, t0, t1, args, tid) -> None:
-        ev = {"name": name, "cat": cat, "ph": "X", "pid": self._pid,
-              "tid": tid, "ts": self._us(t0),
-              "dur": max(self._us(t1) - self._us(t0), 0.0)}
-        if args:
-            ev["args"] = args
+    def step(self, name: str, step_num: int, **args) -> _Open:
+        """A span that the profiler marks as step ``step_num``."""
+        return self._open(name, args, jax.profiler.StepTraceAnnotation(
+            name, step_num=step_num))
+
+    def spans(self, t0: float = -math.inf, t1: float = math.inf
+              ) -> list[Span]:
+        """The held spans that lie inside [t0, t1], oldest end first."""
         with self._lock:
-            self._events.append(ev)
+            held = list(self._ring)
+        return [s for s in held if s.t0 >= t0 and s.t1 <= t1]
 
-    def span(self, name: str, cat: str = "host", **args):
-        """Context manager recording one complete event (no-op singleton
-        when disabled — safe on hot loops)."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, cat, args or None)
-
-    def instant(self, name: str, cat: str = "host", **args) -> None:
-        if not self.enabled:
-            return
-        ev = {"name": name, "cat": cat, "ph": "i", "s": "p",
-              "pid": self._pid, "tid": TID_HOST,
-              "ts": self._us(time.perf_counter())}
-        if args:
-            ev["args"] = args
-        with self._lock:
-            self._events.append(ev)
-
-    def counter(self, name: str, **values) -> None:
-        """Chrome-trace counter track (ph "C") — e.g. queue depth over
-        time next to the spans."""
-        if not self.enabled:
-            return
-        with self._lock:
-            self._events.append({"name": name, "ph": "C",
-                                 "pid": self._pid, "tid": TID_HOST,
-                                 "ts": self._us(time.perf_counter()),
-                                 "args": values})
-
-    # -------------------------------------------------- jit-mark pairing
-    def _jit_begin(self, name: str) -> None:
-        with self._lock:
-            self._open.setdefault(name, []).append(time.perf_counter())
-
-    def _jit_end(self, name: str, cat: str, args: dict | None) -> float:
-        t1 = time.perf_counter()
-        with self._lock:
-            stack = self._open.get(name)
-            t0 = stack.pop() if stack else None
-        if t0 is None:  # unmatched (callback reorder): degrade to instant
-            with self._lock:
-                self._events.append({"name": name, "cat": cat, "ph": "i",
-                                     "s": "p", "pid": self._pid,
-                                     "tid": TID_JIT, "ts": self._us(t1)})
-            return 0.0
-        self._complete(name, cat, t0, t1, args, TID_JIT)
-        return t1 - t0
-
-    # ------------------------------------------------------------ export
-    def events(self) -> list[dict]:
-        with self._lock:
-            return list(self._events)
-
-    def save(self, path) -> dict:
-        """Write Chrome-trace JSON (Perfetto-loadable) and return the
-        document."""
-        doc = {
-            "traceEvents": self.events(),
-            "displayTimeUnit": "ms",
-            "metadata": {"schema_version": TRACE_SCHEMA_VERSION,
-                         "producer": "repro.obs",
-                         "pid": self._pid},
-        }
-        with open(path, "w") as f:
-            json.dump(doc, f, indent=1)
-        return doc
-
-    @staticmethod
-    def load(path) -> dict:
-        with open(path) as f:
-            return json.load(f)
+    def oldest(self) -> float:
+        """The time from which the ring holds every span: a span that
+        ended after it is held (``-inf`` until the ring first drops
+        one)."""
+        return self._held_from
 
 
 _TRACER = Tracer()
@@ -193,99 +145,16 @@ def tracer() -> Tracer:
     return _TRACER
 
 
-def enable_tracing(*, clear: bool = False) -> Tracer:
-    _TRACER.enable(clear=clear)
-    return _TRACER
+def compiles() -> int:
+    """Backend compiles in this process so far (``jax_compiles_total``)."""
+    return int(M.registry().value("counter", "jax_compiles_total") or 0)
 
 
-def disable_tracing() -> None:
-    _TRACER.disable()
+def _on_event_duration(event: str, secs: float, **kw) -> None:
+    if event == COMPILE_EVENT:
+        M.registry().counter(
+            "jax_compiles_total",
+            help="XLA backend compiles in this process").inc()
 
 
-# ------------------------------------------------------------- jit marks
-def _probe(value):
-    """A scalar view of ``value`` for the callback operand — the
-    callback must depend on the array without shipping the whole buffer
-    to the host."""
-    import jax.numpy as jnp
-
-    if hasattr(value, "ndim") and value.ndim > 0:
-        return value[(0,) * value.ndim]
-    return jnp.asarray(value)
-
-
-def jit_begin(value, name: str):
-    """Stage a begin-mark whose firing depends on ``value`` being
-    computed; returns ``value`` unchanged.  No-op (nothing staged) when
-    tracing is off at trace time."""
-    t = _TRACER
-    if not t.enabled:
-        return value
-    global jit_marks_staged
-    jit_marks_staged += 1
-    import jax
-
-    jax.debug.callback(lambda _: t._jit_begin(name), _probe(value))
-    return value
-
-
-def jit_end(value, name: str, cat: str = "jit", args: dict | None = None,
-            hist: str | None = None, hist_labels: dict | None = None):
-    """Stage the matching end-mark on ``value`` (the op's output);
-    returns ``value`` unchanged.  When ``hist`` is given, the measured
-    duration is also observed into that registry histogram (e.g.
-    per-collective seconds) — attribution lands in both the trace and
-    the metrics snapshot."""
-    t = _TRACER
-    if not t.enabled:
-        return value
-    global jit_marks_staged
-    jit_marks_staged += 1
-    import jax
-
-    labels = dict(hist_labels or {})
-
-    def cb(_):
-        dur = t._jit_end(name, cat, args)
-        if hist is not None:
-            from repro.obs import metrics as M
-
-            M.registry().histogram(hist, **labels).observe(dur)
-
-    jax.debug.callback(cb, _probe(value))
-    return value
-
-
-# ------------------------------------------------------------ validation
-def validate_trace(doc: dict) -> list[str]:
-    """Schema check for a saved trace document (empty list == valid)."""
-    errs: list[str] = []
-    if not isinstance(doc, dict):
-        return ["trace is not an object"]
-    evs = doc.get("traceEvents")
-    if not isinstance(evs, list):
-        return ["traceEvents missing or not a list"]
-    meta = doc.get("metadata", {})
-    if meta.get("schema_version") != TRACE_SCHEMA_VERSION:
-        errs.append(f"metadata.schema_version="
-                    f"{meta.get('schema_version')!r} != "
-                    f"{TRACE_SCHEMA_VERSION}")
-    for i, ev in enumerate(evs):
-        if not isinstance(ev, dict):
-            errs.append(f"traceEvents[{i}] not an object")
-            continue
-        for f in ("name", "ph", "ts", "pid", "tid"):
-            if f not in ev:
-                errs.append(f"traceEvents[{i}] ({ev.get('name')}) "
-                            f"missing {f!r}")
-        if ev.get("ph") == "X" and "dur" not in ev:
-            errs.append(f"traceEvents[{i}] complete event missing dur")
-    return errs
-
-
-def validate_trace_file(path) -> list[str]:
-    try:
-        doc = json.loads(open(path).read())
-    except (OSError, ValueError) as e:
-        return [f"unreadable trace {path}: {e}"]
-    return validate_trace(doc)
+jax.monitoring.register_event_duration_secs_listener(_on_event_duration)

@@ -53,29 +53,6 @@ from repro.serving.scheduler import Scheduler
 DEPTH_BUCKETS = (0, 1, 2, 4, 8, 16, 32, 64, 128, 256)
 
 
-class _StepTimer:
-    """Times one engine iteration into serving_step_s{phase=}.  Wall
-    time includes device sync only when tracing is on (the engine blocks
-    inside the span then); untraced it measures the host dispatch path,
-    which is still the right signal for engine-loop overhead."""
-
-    __slots__ = ("engine", "phase", "t0")
-
-    def __init__(self, engine, phase):
-        self.engine = engine
-        self.phase = phase
-
-    def __enter__(self):
-        self.t0 = self.engine._clock()
-        return self
-
-    def __exit__(self, *exc):
-        obs.registry().histogram(
-            "serving_step_s", help="engine iteration wall time",
-            phase=self.phase).observe(self.engine._clock() - self.t0)
-        return False
-
-
 class Engine:
     """Continuous-batching engine.
 
@@ -227,6 +204,7 @@ class Engine:
         self.rejected: list[Sequence] = []  # shed / cancelled / ...
         self.num_prefill_steps = 0
         self.num_decode_steps = 0
+        self.num_iterations = 0  # every step() call; the profiler's step_num
         # peak concurrently-admitted sequences observed before the first
         # preemption — the capacity headline BENCH_serve.json reports
         self.max_resident_seqs = 0
@@ -361,16 +339,15 @@ class Engine:
             out.append(jax.device_put(a, sharding))
         return tuple(out)
 
-    def _call_step(self, params, pool, *host_arrays):
+    def _call_step(self, params, pool, *inputs):
         """Invoke the shared jitted step with this engine's exec policy
         (and mesh) active — both are consumed at trace time (first call
         per phase shape), where plan() finds the cache pre-warmed by
         ``_resolve_plans``."""
         with self._mesh_ctx(), dispatch.using_policy(self._policy):
-            return self._step_fn(params, pool,
-                                 *self._put_inputs(*host_arrays))
+            return self._step_fn(params, pool, *inputs)
 
-    def _run_step(self, *host_arrays):
+    def _run_step(self, *inputs):
         """The guarded jitted-step call: watchdog timing, fault
         injection, and bounded retry-with-backoff.
 
@@ -400,8 +377,7 @@ class Engine:
                     ev = faults.fire("step_fail")
                     if ev is not None:
                         raise faults.InjectedFault("step_fail", ev)
-                    return self._call_step(self.params, self.kv,
-                                           *host_arrays)
+                    return self._call_step(self.params, self.kv, *inputs)
                 finally:
                     if wd is not None:
                         wd.step_finished()
@@ -478,21 +454,16 @@ class Engine:
         self._step_fn = jax.jit(self._raw_step, donate_argnums=(1,))
         with contextlib.suppress(Exception):
             self.exec_plans = self._resolve_plans(self._raw_step)
-        obs.tracer().instant("engine.replan", cat="serving",
-                             reason=reason, quarantined=",".join(suspects))
 
     def _check_finite(self, rows, ok, done: list) -> set:
         """NaN/Inf logit guard.  ``rows``: [(seq, row_index)] consuming
         a token this step; ``ok``: the device-computed per-row finite
-        flags.  Non-finite rows (organic or injected) are quarantined —
-        the sequence is cancelled cleanly instead of poisoning the
-        batch — and once ``nan_replan_after`` events accumulate the
-        suspect backend is quarantined too.  Returns the ids of
-        quarantined sequences."""
-        if not rows:
-            return set()
-        ok_host = np.asarray(ok)
-        bad = {i for (_, i) in rows if not bool(ok_host[i])}
+        flags, already read back to the host.  Non-finite rows (organic
+        or injected) are quarantined — the sequence is cancelled cleanly
+        instead of poisoning the batch — and once ``nan_replan_after``
+        events accumulate the suspect backend is quarantined too.
+        Returns the ids of quarantined sequences."""
+        bad = {i for (_, i) in rows if not bool(ok[i])}
         ev = faults.fire("nan_logits")
         if ev is not None:
             bad.add(rows[int(ev.rng.integers(len(rows)))][1])
@@ -563,8 +534,6 @@ class Engine:
         self.scheduler.add(seq)
         obs.registry().counter("serving_requests_submitted_total",
                                help="requests queued").inc()
-        obs.tracer().instant("request.submit", cat="serving",
-                             rid=req.rid, prompt_tokens=len(req.prompt))
         return seq
 
     def _shed(self, seq: Sequence, reason: str) -> Sequence:
@@ -577,8 +546,6 @@ class Engine:
             "serving_shed_total",
             help="requests rejected at admission (load shedding)",
             reason=reason).inc()
-        obs.tracer().instant("request.shed", cat="serving",
-                             rid=seq.req.rid, reason=reason)
         return seq
 
     def cancel(self, seq: Sequence, reason: str = "cancelled") -> Sequence:
@@ -595,9 +562,6 @@ class Engine:
             "serving_cancelled_total",
             help="live sequences cancelled (deadline/disconnect/guard)",
             reason=reason).inc()
-        obs.tracer().instant("request.cancel", cat="serving",
-                             rid=seq.req.rid, reason=reason,
-                             generated=len(seq.generated))
         return seq
 
     def _enforce_deadlines(self, done: list) -> None:
@@ -617,8 +581,34 @@ class Engine:
         """One engine iteration (one prefill chunk OR one decode batch).
         Returns sequences that *terminated* this iteration — finished
         normally (status 'ok') or cancelled (deadline / disconnect /
-        quarantine; see ``Sequence.status``)."""
+        quarantine; see ``Sequence.status``).
+
+        The iteration is one ``engine.iteration`` span (a profiler step,
+        ``step_num`` the iteration count) whose children are, in order,
+        ``engine.schedule``, ``engine.prepare``, ``engine.launch``,
+        ``engine.fetch`` (the host waiting on the chip) and
+        ``engine.emit``; a prefill chunk that is not its prompt's last
+        reads nothing back and has no ``engine.fetch``."""
         done: list[Sequence] = []
+        tr = obs.tracer()
+        self.num_iterations += 1
+        with tr.step("engine.iteration", self.num_iterations) as it:
+            with tr.span("engine.schedule"):
+                act = self._schedule(done)
+            if act is not None:
+                if act[0] == "prefill":
+                    self._prefill_chunk(act[1], act[2], act[3], done, it)
+                else:
+                    self._decode_batch(act[1], done, it)
+                if self._hang_flag.is_set():
+                    self._escalate_hang()
+        if "kind" in it.args:
+            obs.registry().histogram(
+                "serving_step_s", help="engine iteration wall time",
+                phase=it.args["kind"]).observe(it.duration)
+        return done
+
+    def _schedule(self, done: list):
         injecting = faults.active() is not None
         if injecting:
             ev = faults.fire("latency")
@@ -629,21 +619,13 @@ class Engine:
             self._enforce_deadlines(done)
         act = self.scheduler.schedule()
         self._sample_depths()
-        if act is None:
-            if self.scheduler.waiting and not injecting:
-                raise RuntimeError(
-                    "engine stalled: waiting requests but nothing running "
-                    "and the head cannot be admitted")
-            # under injection a transient (injected OOM) admission miss
-            # is expected — report idle and let the caller re-step
-            return done
-        if act[0] == "prefill":
-            self._prefill_chunk(act[1], act[2], act[3], done)
-        else:
-            self._decode_batch(act[1], done)
-        if self._hang_flag.is_set():
-            self._escalate_hang()
-        return done
+        if act is None and self.scheduler.waiting and not injecting:
+            raise RuntimeError(
+                "engine stalled: waiting requests but nothing running "
+                "and the head cannot be admitted")
+        # under injection a transient (injected OOM) admission miss is
+        # expected — the iteration is idle and the caller re-steps
+        return act
 
     def _maybe_disconnect(self, done: list) -> None:
         live = [s for s in self.scheduler.running if not s.done]
@@ -669,85 +651,102 @@ class Engine:
         reg.histogram("serving_queue_depth_samples",
                       help="queue depth at each engine iteration",
                       buckets=DEPTH_BUCKETS).observe(depth)
-        obs.tracer().counter("queue", waiting=depth, running=running)
+
+    def _launch(self, inputs):
+        """The jitted step, as one ``engine.launch`` span; its
+        ``compiled`` arg is set when a backend compile happened inside."""
+        with obs.tracer().span("engine.launch") as sp:
+            c0 = obs.compiles()
+            out = self._run_step(*inputs)
+            if obs.compiles() > c0:
+                sp.args["compiled"] = True
+        return out
 
     def _prefill_chunk(self, seq: Sequence, start: int, end: int,
-                       done: list) -> None:
+                       done: list, it) -> None:
+        tr = obs.tracer()
         C = self.prefill_chunk
         toks = seq.prefill_tokens
         n = end - start
-        tokens = np.zeros((1, C), np.int32)
-        tokens[0, :n] = toks[start:end]
-        positions = (start + np.arange(C, dtype=np.int32))[None]
-        ws = kv_blocks.write_slots(seq.blocks, start, n, C,
-                                   self.block_size)[None]
-        vs = kv_blocks.view_slots(seq.blocks, self.max_blocks_per_seq,
-                                  self.block_size)[None]
-        last = np.array([n - 1], np.int32)
-        with obs.tracer().span("engine.prefill_chunk", cat="serving",
-                               rid=seq.req.rid, start=start, end=end), \
-                self._step_timer("prefill"):
-            out = self._run_step(tokens, positions, ws, vs, last)
-            if out is None:  # pool rebuilt; seq was preempted, re-prefills
-                return
-            tok, logits, ok, self.kv = out
-            if obs.tracer().enabled:  # sync so the span covers compute,
-                jax.block_until_ready(tok)  # never on the untraced path
-        self.num_prefill_steps += 1
-        seq.prefill_pos = end
-        if end == len(toks):  # prompt fully ingested -> first new token
-            if self._check_finite([(seq, 0)], ok, done):
+        it.args.update(kind="prefill", rows=n, rid=seq.req.rid)
+        with tr.span("engine.prepare"):
+            tokens = np.zeros((1, C), np.int32)
+            tokens[0, :n] = toks[start:end]
+            positions = (start + np.arange(C, dtype=np.int32))[None]
+            ws = kv_blocks.write_slots(seq.blocks, start, n, C,
+                                       self.block_size)[None]
+            vs = kv_blocks.view_slots(seq.blocks, self.max_blocks_per_seq,
+                                      self.block_size)[None]
+            last = np.array([n - 1], np.int32)
+            inputs = self._put_inputs(tokens, positions, ws, vs, last)
+        out = self._launch(inputs)
+        if out is None:  # pool rebuilt; seq was preempted, re-prefills
+            return
+        tok, logits, ok, self.kv = out
+        last_chunk = end == len(toks)
+        if last_chunk:  # prompt fully ingested -> first new token
+            with tr.span("engine.fetch"):
+                ok = np.asarray(ok)
+        with tr.span("engine.emit"):
+            self.num_prefill_steps += 1
+            seq.prefill_pos = end
+            if not last_chunk or self._check_finite([(seq, 0)], ok, done):
                 return
             seq.phase = Phase.DECODE
             self._append(seq, self._pick(seq, tok[0], logits[0]), done)
 
-    def _decode_batch(self, seqs: list[Sequence], done: list) -> None:
-        active = []
-        for seq in seqs:
-            if seq.phase is not Phase.DECODE:
-                continue  # evicted as a preemption victim this iteration
-            if self.scheduler.grow_for_decode(seq):
-                active.append(seq)
-        if not active:
-            return
-        B, bs = self.max_slots, self.block_size
-        W = self.max_blocks_per_seq * bs
-        tokens = np.zeros((B, 1), np.int32)
-        positions = np.zeros((B, 1), np.int32)
-        # idle slots write to (distinct offsets of) the scratch block and
-        # view only scratch — static shapes, no effect on live sequences
-        ws = (np.arange(B, dtype=np.int32) % bs)[:, None]
-        vs = np.zeros((B, W), np.int32)
-        for seq in active:
-            b = seq.slot
-            tokens[b, 0] = seq.generated[-1]
-            positions[b, 0] = seq.num_tokens - 1
-            ws[b] = kv_blocks.write_slots(seq.blocks, seq.num_tokens - 1,
-                                          1, 1, bs)
-            vs[b] = kv_blocks.view_slots(seq.blocks, self.max_blocks_per_seq,
-                                         bs)
-        last = np.zeros((B,), np.int32)
-        with obs.tracer().span("engine.decode_step", cat="serving",
-                               batch=len(active)), \
-                self._step_timer("decode"):
-            out = self._run_step(tokens, positions, ws, vs, last)
-            if out is None:  # pool rebuilt; everyone re-prefills
+    def _decode_batch(self, seqs: list[Sequence], done: list, it) -> None:
+        tr = obs.tracer()
+        with tr.span("engine.prepare"):
+            active = []
+            for seq in seqs:
+                if seq.phase is not Phase.DECODE:
+                    continue  # evicted as a preemption victim this iteration
+                if self.scheduler.grow_for_decode(seq):
+                    active.append(seq)
+            if not active:
                 return
-            tok, logits, ok, self.kv = out
-            if obs.tracer().enabled:
-                jax.block_until_ready(tok)
-        self.num_decode_steps += 1
-        obs.registry().histogram(
-            "serving_decode_batch_occupancy",
-            help="live rows per decode iteration (of max_slots)",
-            buckets=DEPTH_BUCKETS).observe(len(active))
-        # only live rows are guarded — idle slots attend scratch garbage
-        bad = self._check_finite([(s, s.slot) for s in active], ok, done)
-        for seq in active:
-            if id(seq) in bad:
-                continue
-            self._append(seq, self._pick(seq, tok[seq.slot],
-                                         logits[seq.slot]), done)
+            it.args.update(kind="decode", rows=len(active))
+            B, bs = self.max_slots, self.block_size
+            W = self.max_blocks_per_seq * bs
+            tokens = np.zeros((B, 1), np.int32)
+            positions = np.zeros((B, 1), np.int32)
+            # idle slots write to (distinct offsets of) the scratch block
+            # and view only scratch — static shapes, no effect on live
+            # sequences
+            ws = (np.arange(B, dtype=np.int32) % bs)[:, None]
+            vs = np.zeros((B, W), np.int32)
+            for seq in active:
+                b = seq.slot
+                tokens[b, 0] = seq.generated[-1]
+                positions[b, 0] = seq.num_tokens - 1
+                ws[b] = kv_blocks.write_slots(seq.blocks,
+                                              seq.num_tokens - 1, 1, 1, bs)
+                vs[b] = kv_blocks.view_slots(seq.blocks,
+                                             self.max_blocks_per_seq, bs)
+            last = np.zeros((B,), np.int32)
+            inputs = self._put_inputs(tokens, positions, ws, vs, last)
+        out = self._launch(inputs)
+        if out is None:  # pool rebuilt; everyone re-prefills
+            return
+        tok, logits, ok, self.kv = out
+        with tr.span("engine.fetch"):
+            ok = np.asarray(ok)
+        with tr.span("engine.emit"):
+            self.num_decode_steps += 1
+            obs.registry().histogram(
+                "serving_decode_batch_occupancy",
+                help="live rows per decode iteration (of max_slots)",
+                buckets=DEPTH_BUCKETS).observe(len(active))
+            # only live rows are guarded — idle slots attend scratch
+            # garbage
+            bad = self._check_finite([(s, s.slot) for s in active], ok,
+                                     done)
+            for seq in active:
+                if id(seq) in bad:
+                    continue
+                self._append(seq, self._pick(seq, tok[seq.slot],
+                                             logits[seq.slot]), done)
 
     # ---------------------------------------------------------- sampling
     def _pick(self, seq: Sequence, greedy_tok, logits) -> int:
@@ -759,9 +758,6 @@ class Engine:
                 np.random.SeedSequence([self._sample_seed, seq.req.rid])))
         scaled = np.asarray(logits, np.float64) / seq.req.temperature
         return int(np.argmax(scaled + rng.gumbel(size=scaled.shape)))
-
-    def _step_timer(self, phase: str):
-        return _StepTimer(self, phase)
 
     def _append(self, seq: Sequence, token: int, done: list) -> None:
         t = self.now
@@ -789,10 +785,6 @@ class Engine:
             reg.histogram("serving_request_latency_s",
                           help="arrival -> last token"
                           ).observe(t - seq.t_arrival)
-            obs.tracer().instant("request.finish", cat="serving",
-                                 rid=seq.req.rid,
-                                 new_tokens=len(seq.generated),
-                                 preemptions=seq.preemptions)
 
     # --------------------------------------------------------------- run
     def run(self, requests, *, wait_for_arrivals: bool = True

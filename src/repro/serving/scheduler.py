@@ -104,9 +104,6 @@ class Scheduler:
             obs.registry().counter(
                 "serving_admissions_total",
                 help="sequences admitted to a decode slot").inc()
-            obs.tracer().instant("scheduler.admit", cat="serving",
-                                 rid=seq.req.rid, slot=seq.slot,
-                                 blocks=len(seq.blocks))
 
     # -------------------------------------------------------- scheduling
     def schedule(self):
@@ -153,10 +150,6 @@ class Scheduler:
         reg.counter("serving_evicted_blocks_total",
                     help="KV blocks freed by preemption").inc(
                         len(victim.blocks))
-        obs.tracer().instant("scheduler.preempt", cat="serving",
-                             rid=victim.req.rid,
-                             blocks=len(victim.blocks),
-                             generated=len(victim.generated))
         victim.t_last_token = None  # next gap is requeue, not decode cadence
         self.pool.free(victim.blocks)
         victim.blocks = []

@@ -1,12 +1,13 @@
-"""Observability tests: metric registry semantics, snapshot/trace schema
-validation, Chrome-trace span recording (host spans + jit marks under
-jit/scan), the zero-overhead-when-disabled contract, and the engine's
-token-identity invariant with tracing on vs off."""
+"""Observability tests: metric registry semantics, snapshot schema
+validation, the span ring and its windowing, the engine's spans in a
+profiler trace, the model's device-side scopes, the compile counter, and
+the engine's token-identity invariant with a profiler trace running and
+without one."""
 
 import json
+import math
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -14,17 +15,8 @@ from repro import obs
 from repro.obs import trace as TR
 from repro.obs.metrics import Registry
 
-
-@pytest.fixture(autouse=True)
-def _clean_tracer():
-    """Tracing is process-global; leave it off and empty around every
-    test so obs tests cannot leak spans into each other (or stage
-    callbacks into other tests' compiles)."""
-    obs.disable_tracing()
-    obs.tracer().clear()
-    yield
-    obs.disable_tracing()
-    obs.tracer().clear()
+ENGINE_CHILDREN = ("engine.schedule", "engine.prepare", "engine.launch",
+                   "engine.fetch", "engine.emit")
 
 
 # ------------------------------------------------------------- registry
@@ -123,93 +115,105 @@ def test_prometheus_text_and_endpoint():
 
 
 # --------------------------------------------------------------- tracer
-def test_host_span_nesting_and_roundtrip(tmp_path):
-    obs.enable_tracing(clear=True)
-    with obs.tracer().span("outer", cat="test", k=1):
-        with obs.tracer().span("inner", cat="test"):
+def test_ring_nesting_parent_and_self_time():
+    tr = TR.Tracer()
+    with tr.span("outer", k=1) as outer:
+        with tr.span("inner") as inner:
             pass
-    obs.tracer().instant("mark", cat="test")
-    obs.tracer().counter("queue", waiting=3)
-    p = tmp_path / "t.json"
-    doc = obs.tracer().save(p)
-    assert obs.validate_trace(doc) == []
-    assert obs.validate_trace_file(p) == []
-    loaded = obs.tracer().load(p)
-    by_name = {e["name"]: e for e in loaded["traceEvents"]}
-    assert by_name["outer"]["ph"] == by_name["inner"]["ph"] == "X"
-    # inner completes first and sits inside outer's window
-    assert by_name["inner"]["dur"] <= by_name["outer"]["dur"]
-    assert by_name["inner"]["ts"] >= by_name["outer"]["ts"]
-    assert by_name["mark"]["ph"] == "i"
-    assert by_name["queue"]["ph"] == "C"
-    assert by_name["queue"]["args"] == {"waiting": 3}
+        with tr.span("inner2"):
+            pass
+        outer.args["late"] = True  # args may grow until exit
+    got = tr.spans()
+    # spans close in order: children first, the parent last
+    assert [s.name for s in got] == ["inner", "inner2", "outer"]
+    assert inner.parent is outer and got[1].parent is outer
+    assert outer.parent is None
+    assert outer.args == {"k": 1, "late": True}
+    assert outer.t0 <= inner.t0 <= inner.t1 <= outer.t1
+    kids = [s for s in got if s.parent is outer]
+    self_s = outer.duration - sum(s.duration for s in kids)
+    assert 0.0 <= self_s <= outer.duration
 
 
-def test_jit_marks_pair_under_jit():
-    obs.enable_tracing(clear=True)
-
-    def g(x):
-        x = TR.jit_begin(x, "outer")
-        y = TR.jit_begin(x, "inner")
-        y = TR.jit_end(y + 1.0, "inner", cat="test")
-        return TR.jit_end(y * 2.0, "outer", cat="test")
-
-    jax.block_until_ready(jax.jit(g)(jnp.ones((2,))))
-    jax.effects_barrier()
-    evs = {e["name"]: e for e in obs.tracer().events()}
-    assert evs["outer"]["ph"] == evs["inner"]["ph"] == "X"
-    assert evs["inner"]["dur"] <= evs["outer"]["dur"]
-
-
-def test_jit_marks_under_scan_fire_per_iteration():
-    """Marks staged once at trace time fire every scan iteration, each
-    pairing into its own complete event."""
-    obs.enable_tracing(clear=True)
-
-    def step(c, _):
-        c = TR.jit_begin(c, "scan.step")
-        c = TR.jit_end(c * 2.0, "scan.step", cat="test")
-        return c, c
-
-    f = jax.jit(lambda x: jax.lax.scan(step, x, None, length=4))
-    out, _ = f(jnp.ones(()))
-    jax.block_until_ready(out)
-    jax.effects_barrier()
-    evs = [e for e in obs.tracer().events() if e["name"] == "scan.step"]
-    assert len(evs) == 4
-    assert all(e["ph"] == "X" for e in evs)
+def test_spans_windowing_and_ring_bound():
+    tr = TR.Tracer(maxlen=4)
+    for i in range(3):
+        with tr.span(f"s{i}"):
+            pass
+    first = tr.spans()
+    assert tr.oldest() == -math.inf  # nothing dropped yet
+    # [t0, t1] keeps the spans that lie wholly inside it
+    assert [s.name for s in tr.spans(first[1].t0, first[2].t1)] \
+        == ["s1", "s2"]
+    assert tr.spans(first[0].t0, first[0].t1 - 1e-9) == []
+    for i in range(3, 6):
+        with tr.span(f"s{i}"):
+            pass
+    held = tr.spans()
+    assert [s.name for s in held] == ["s2", "s3", "s4", "s5"]
+    # everything that ended after the last dropped span is still held
+    assert tr.oldest() == first[1].t1
+    assert all(s.t1 >= tr.oldest() for s in held)
+    assert TR.RING_SPANS == 65_536
+    assert obs.tracer()._ring.maxlen == TR.RING_SPANS
 
 
-def test_jit_end_records_histogram():
-    obs.enable_tracing(clear=True)
-    obs.registry().reset(prefix="t_kernel_")
+def test_ring_holds_every_thread_s_spans_under_contention():
+    """More threads than cores, each nesting spans on its own stack: no
+    append is lost, every parent is the same thread's outer span, and
+    every span the ring dropped ended by ``oldest()``."""
+    import sys
+    import threading
 
-    def g(x):
-        x = TR.jit_begin(x, "m")
-        return TR.jit_end(x * 2.0, "m", hist="t_kernel_s",
-                          hist_labels={"k": "8"})
+    n_threads, per_thread = 16, 200
+    whole, small = TR.Tracer(), TR.Tracer(maxlen=500)
+    opened = [[] for _ in range(n_threads)]  # the small ring's spans
 
-    jax.block_until_ready(jax.jit(g)(jnp.ones((8,))))
-    jax.effects_barrier()
-    assert obs.registry().value("histogram", "t_kernel_s", k="8") == 1
+    def work(i):
+        for _ in range(per_thread):
+            for tr in (whole, small):
+                with tr.span("outer", thread=i) as outer:
+                    with tr.span("inner", thread=i) as inner:
+                        pass
+            opened[i] += [inner, outer]
+
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(prev)
+    assert not any(t.is_alive() for t in threads)
+    got = whole.spans()
+    assert len(got) == 2 * n_threads * per_thread
+    for s in got:
+        if s.name == "inner":
+            assert s.parent.name == "outer"
+            assert s.parent.args["thread"] == s.args["thread"]
+        else:
+            assert s.parent is None
+    held = {id(s) for s in small.spans()}
+    assert len(held) == 500
+    dropped = [s for spans in opened for s in spans if id(s) not in held]
+    assert len(dropped) == 2 * n_threads * per_thread - 500
+    assert all(s.t1 <= small.oldest() for s in dropped)
 
 
 def test_tracing_off_is_zero_overhead():
-    """The hard contract: with tracing disabled, span() returns the
-    shared no-op singleton and jit_begin/jit_end stage NOTHING into the
-    jitted computation (jit_marks_staged counts stagings)."""
-    assert not obs.tracer().enabled
-    assert obs.tracer().span("a") is obs.tracer().span("b")
-    before = TR.jit_marks_staged
+    """Nothing is staged into jitted code: the lowered engine step holds
+    no host-callback custom call."""
+    import re
 
-    def g(x):
-        x = TR.jit_begin(x, "m")
-        return TR.jit_end(x * 2.0, "m")
-
-    jax.block_until_ready(jax.jit(g)(jnp.ones((4,))))
-    jax.effects_barrier()
-    assert TR.jit_marks_staged == before
-    assert obs.tracer().events() == []
+    eng = _engine()
+    for decode in (True, False):
+        text = _lowered_step(eng, debug=False, decode=decode)
+        targets = re.findall(r"custom_call @([\w.]+)", text)
+        assert not [t for t in targets if "callback" in t], targets
 
 
 # ----------------------------------------------------------- cost model
@@ -280,45 +284,161 @@ def _small_model():
     return T.init_params(jax.random.PRNGKey(0), CFG), CFG
 
 
-def _drive(params, cfg):
-    from repro.serving import Engine, Request
+def _engine(**kw):
+    from repro.serving import Engine
 
+    params, cfg = _small_model()
+    return Engine(params, cfg, **dict(dict(
+        max_slots=2, block_size=4, prefill_chunk=4, max_model_len=32), **kw))
+
+
+def _prompts(cfg):
     rng = np.random.default_rng(7)
-    prompts = [tuple(int(t) for t in rng.integers(0, cfg.vocab_size,
-                                                  size=n))
-               for n in (5, 9)]
-    eng = Engine(params, cfg, max_slots=2, block_size=4, prefill_chunk=4,
-                 max_model_len=32)
+    return [tuple(int(t) for t in rng.integers(0, cfg.vocab_size, size=n))
+            for n in (5, 9)]
+
+
+def _drive(params, cfg):
+    from repro.serving import Request
+
+    eng = _engine()
     res = eng.run([Request(rid=i, prompt=p, max_new_tokens=4)
-                   for i, p in enumerate(prompts)])
+                   for i, p in enumerate(_prompts(cfg))])
     return eng, {rid: seq.generated for rid, seq in res.items()}
 
 
-def test_engine_tokens_identical_tracing_on_vs_off():
-    """Tracing must be observational only: the engine generates the
-    exact same greedy tokens with tracing enabled as disabled, and the
-    traced run yields the request-lifecycle + gemm spans."""
+def _lowered_step(eng, *, debug: bool, decode: bool = True) -> str:
+    """The engine's jitted step lowered at its decode (or prefill) shape,
+    with the engine's policy active, as StableHLO text."""
+    from repro import dispatch
+
+    nb, nt = (eng.max_slots, 1) if decode else (1, eng.prefill_chunk)
+    W = eng.max_blocks_per_seq * eng.block_size
+    ints = [np.zeros(s, np.int32) for s in
+            ((nb, nt), (nb, nt), (nb, nt), (nb, W), (nb,))]
+    with dispatch.using_policy(eng._policy):
+        lowered = eng._step_fn.lower(eng.params, eng.kv, *ints)
+    return lowered.as_text(debug_info=debug)
+
+
+def _host_events(trace_dir):
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+
+    pb = sorted(Path(trace_dir).rglob("*.xplane.pb"))[-1]
+    return [e for p in ProfileData.from_file(str(pb)).planes
+            if p.name.startswith("/host:") for line in p.lines
+            for e in line.events]
+
+
+def test_engine_tokens_identical_tracing_on_vs_off(tmp_path):
+    """Spans are observational only: the engine generates the exact
+    same greedy tokens with a jax.profiler trace running as without."""
     params, cfg = _small_model()
     _, toks_off = _drive(params, cfg)
-
-    obs.enable_tracing(clear=True)  # BEFORE build: jit marks stage now
-    _, toks_on = _drive(params, cfg)
-    jax.effects_barrier()
-    obs.disable_tracing()
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        _, toks_on = _drive(params, cfg)
     assert toks_on == toks_off
+    names = {e.name for e in _host_events(tmp_path / "trace")}
+    assert {"engine.iteration", *ENGINE_CHILDREN} <= names
 
-    names = {e["name"] for e in obs.tracer().events()}
-    assert "engine.prefill_chunk" in names
-    assert "engine.decode_step" in names
-    assert any(n.startswith("gemm.") for n in names)
+
+def test_profiler_trace_holds_engine_iteration_and_its_children(tmp_path):
+    from repro.serving import Request
+
+    params, cfg = _small_model()
+    eng = _engine()
+    # a one-chunk prompt: its prefill reads the first token back
+    eng.submit(Request(rid=0, prompt=(1, 2, 3), max_new_tokens=5))
+    eng.step()  # compile both shapes outside the traced steps
+    eng.step()
+    with jax.profiler.trace(str(tmp_path)):
+        eng.step()
+        eng.step()
+    evs = sorted((e for e in _host_events(tmp_path)
+                  if e.name.startswith("engine.")),
+                 key=lambda e: e.start_ns)
+    its = [e for e in evs if e.name == "engine.iteration"]
+    assert len(its) == 2
+    for it in its:
+        end = it.start_ns + it.duration_ns
+        kids = [e.name for e in evs if e.name != "engine.iteration"
+                and it.start_ns <= e.start_ns
+                and e.start_ns + e.duration_ns <= end]
+        assert tuple(kids) == ENGINE_CHILDREN
+        stats = dict(it.stats)
+        assert stats["kind"] == "decode" and "step_num" in stats
+    # the ring saw the same two iterations, children in the same order
+    ring = [s for s in obs.tracer().spans()
+            if s.name == "engine.iteration"][-2:]
+    for it in ring:
+        kids = [s.name for s in obs.tracer().spans(it.t0, it.t1)
+                if s.parent is it]
+        assert tuple(kids) == ENGINE_CHILDREN
+        assert it.args["kind"] == "decode" and it.args["rows"] == 1
+
+
+def test_lowered_step_names_scopes_and_stages_no_callback():
+    eng = _engine()
+    for decode in (True, False):
+        dbg = _lowered_step(eng, debug=True, decode=decode)
+        for scope in ("linear.wq", "linear.down", "linear.lm_head",
+                      "attn.core", "attn.kv_write", "/norm/", "/embed/"):
+            assert scope in dbg, scope
+        assert "callback" not in _lowered_step(eng, debug=False,
+                                               decode=decode)
+
+
+def test_new_shape_sets_compiled_and_counts_compiles():
+    from repro.serving import Request
+
+    # a prefill chunk no other test uses: its first call compiles (the
+    # persistent cache is off, so no earlier run's entry is found)
+    eng = _engine(prefill_chunk=6)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        c0 = obs.compiles()
+        eng.submit(Request(rid=0, prompt=(1, 2, 3), max_new_tokens=2))
+        eng.step()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+    assert obs.compiles() > c0
+    assert obs.registry().value("counter", "jax_compiles_total") \
+        == obs.compiles()
+    launch = [s for s in obs.tracer().spans() if s.name == "engine.launch"]
+    assert launch[-1].args.get("compiled") is True
+    # the same shape again: no compile, no compiled arg
+    eng.submit(Request(rid=1, prompt=(4, 5, 6), max_new_tokens=2))
+    while eng.scheduler.has_work():
+        eng.step()
+    c1 = obs.compiles()
+    eng.submit(Request(rid=2, prompt=(7, 8, 9), max_new_tokens=2))
+    eng.step()
+    assert obs.compiles() == c1
+    launch = [s for s in obs.tracer().spans() if s.name == "engine.launch"]
+    assert "compiled" not in launch[-1].args
+
+
+def test_serving_step_s_is_the_whole_iteration():
+    from repro.serving import Request
+
+    eng = _engine()
+    obs.registry().reset(prefix="serving_")
+    eng.run([Request(rid=0, prompt=(1, 2, 3), max_new_tokens=3)])
+    its = [s for s in obs.tracer().spans() if s.name == "engine.iteration"
+           and s.args.get("kind") == "decode"]
+    h = obs.registry().histogram("serving_step_s", phase="decode")
+    dec = [s.duration for s in its[-h.count:]]
+    assert h.count >= 1
+    assert h.as_dict()["max"] == pytest.approx(max(dec))
 
 
 def test_engine_metrics_edge_cases_and_reset():
-    from repro.serving import Engine, Request
+    from repro.serving import Request
 
-    params, cfg = _small_model()
-    eng = Engine(params, cfg, max_slots=2, block_size=4, prefill_chunk=4,
-                 max_model_len=32)
+    eng = _engine()
     m0 = eng.metrics()  # nothing finished: counts 0, percentiles None
     assert m0["requests"] == 0 and m0["tok_per_s"] == 0.0
     assert m0["latency_p50_s"] is None and m0["ttft_p95_s"] is None

@@ -287,21 +287,6 @@ def test_sentinel_skips_other_partition_and_fast_rows_pass():
     assert report["n_fast"] == 1  # faster than predicted never fails
 
 
-def test_samples_from_snapshot_requires_labels():
-    reg = obs.Registry()
-    reg.histogram("kernel_gemm_s", help="t", backend="msgemm_jnp",
-                  m=16, k=24, b=8, mode="msgemm", d=2,
-                  sb=12).observe(0.5)
-    reg.histogram("kernel_gemm_s", help="t", backend="msgemm_jnp",
-                  m=16, k=24, b=8).observe(0.5)  # pre-tag series
-    samples = pm.samples_from_snapshot(reg.snapshot(), device="cpu",
-                                       interpret=True)
-    assert len(samples) == 1
-    s = samples[0]
-    assert (s.mode, s.d, s.scale_block) == ("msgemm", 2, 12)
-    assert s.measured_s == pytest.approx(0.5)
-
-
 # ------------------------------------------------------------------ CLI
 def test_obs_cli_calibrate_and_check_regressions(monkeypatch, tmp_path,
                                                  capsys):
