@@ -23,6 +23,18 @@ Run::
 
 ``--smoke`` uses the small shape set + 2 reps (the CI configuration);
 the default set adds larger shapes for real-hardware runs.
+
+``--backends`` (TPU only) instead times each msGeMM-mode linear backend
+at every (m, k) linear of the benchmark's configurations: one call of
+``msgemm_pallas`` and ``msgemm_mxu`` exactly as a model step runs it
+(``dispatch.execute`` with the backend forced, so a backend's per-call
+work around its kernel is counted), a dense bf16 matmul of the same
+shape as the MXU's reference point, and each backend's error against
+the dequantized float32 product.  Rows above 16 (prefill) time only
+``msgemm_mxu`` and dense, at the vocab-sized heads::
+
+    PYTHONPATH=src python benchmarks/kernel_microbench.py --backends \
+        --rows 8,16,128 --out linear_backends.json
 """
 
 from __future__ import annotations
@@ -58,16 +70,93 @@ FULL_SHAPES = SMOKE_SHAPES + [
 ]
 
 
-def _bench(fn, reps: int) -> float:
+# (configuration, linear, m, k) of bench/configs/*.json, and the heads
+# that a prefill of more than 16 rows is timed at
+CELL_LINEARS = [
+    ("starcoder2", "wq/wo", 6144, 6144), ("starcoder2", "wk/wv", 512, 6144),
+    ("starcoder2", "up", 24576, 6144), ("starcoder2", "down", 6144, 24576),
+    ("starcoder2", "lm_head", 49152, 6144),
+    ("phi3", "wq/wk/wv/wo", 3072, 3072), ("phi3", "gate/up", 8192, 3072),
+    ("phi3", "down", 3072, 8192), ("phi3", "lm_head", 32064, 3072),
+]
+BACKENDS = ("msgemm_pallas", "msgemm_mxu")
+
+
+def _bench(fn, reps: int, calls: int = 1) -> float:
+    """Best over ``reps`` of the mean time of ``calls`` calls in a row."""
     import jax
 
     jax.block_until_ready(fn())  # compile + warm
     best = float("inf")
     for _ in range(max(reps, 1)):
         t0 = time.perf_counter()
-        jax.block_until_ready(fn())
-        best = min(best, time.perf_counter() - t0)
+        for _ in range(calls):
+            y = fn()
+        jax.block_until_ready(y)
+        best = min(best, (time.perf_counter() - t0) / calls)
     return best
+
+
+def backend_row(m: int, k: int, rows: int, key) -> dict:
+    """One linear shape: each backend's and dense bf16's time per call
+    (ms), and each backend's max error over the dense f32 product's
+    largest magnitude."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro import dispatch
+    from repro.core import linear
+    from repro.core.spec import QuantSpec
+
+    spec = QuantSpec(mode="msgemm", d=3, scale_block=36)
+    kw, kx = jax.random.split(key)
+    params = jax.jit(lambda key: linear.init(key, k, m, spec))(kw)
+    x = jax.random.normal(kx, (rows, k), jnp.float32).astype(jnp.bfloat16)
+
+    def run(name):
+        pol = dispatch.ExecPolicy(backend=name, interpret=False)
+        return jax.jit(lambda p, x: dispatch.execute(
+            p, x, spec, in_dim=k, policy=pol))
+
+    want = run("dense_fallback")(params, x.astype(jnp.float32))
+    scale = float(jnp.max(jnp.abs(want)))
+    row = {"m": m, "k": k, "rows": rows}
+    for name in BACKENDS if rows <= 16 else BACKENDS[1:]:
+        fn = run(name)
+        got = fn(params, x).astype(jnp.float32)
+        row[f"{name}_ms"] = 1e3 * _bench(
+            lambda: fn(params, x), 3,
+            calls=3 if name == "msgemm_pallas" else 20)
+        row[f"{name}_rel_err"] = float(jnp.max(jnp.abs(got - want))) / scale
+    w = jax.random.normal(kw, (m, k), jnp.bfloat16)
+    dense = jax.jit(lambda w, x: x @ w.T)
+    row["dense_bf16_ms"] = 1e3 * _bench(lambda: dense(w, x), 3, calls=20)
+    return row
+
+
+def backends(rows, out: Path | None) -> list:
+    """:func:`backend_row` at every cell linear and row count (rows above
+    16 at the heads only), one JSON line each."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        raise SystemExit(f"--backends needs a TPU, found "
+                         f"{jax.default_backend()!r}")
+    result = []
+    key = jax.random.PRNGKey(0)
+    for cfg, tag, m, k in CELL_LINEARS:
+        for b in rows:
+            if b > 16 and tag != "lm_head":
+                continue
+            key, sub = jax.random.split(key)
+            row = {"config": cfg, "linear": tag, **backend_row(m, k, b, sub),
+                   "device_kind": jax.devices()[0].device_kind}
+            print(json.dumps(row), flush=True)
+            result.append(row)
+    if out is not None:
+        out.parent.mkdir(parents=True, exist_ok=True)
+        out.write_text(json.dumps(result, indent=1) + "\n")
+    return result
 
 
 def _parity_bitexact(d: int, sb: int, m: int, k: int, b: int) -> bool:
@@ -181,7 +270,16 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=None,
                     help="output JSON path (default: "
                          "benchmarks/results/BENCH_kernels.json)")
+    ap.add_argument("--backends", action="store_true",
+                    help="time the msGeMM backends at the benchmark's "
+                         "linear shapes instead (TPU only)")
+    ap.add_argument("--rows", default="8,16",
+                    help="row counts for --backends")
     args = ap.parse_args(argv)
+    if args.backends:
+        backends([int(r) for r in args.rows.split(",")],
+                 Path(args.out) if args.out else None)
+        return 0
     shapes = SMOKE_SHAPES if args.smoke else FULL_SHAPES
     reps = args.reps if args.reps is not None else (2 if args.smoke else 3)
     out = run(shapes=shapes, reps=reps)

@@ -10,7 +10,7 @@ weights at the serve CLI's default d=3, made from ``--seed``.  It serves
 the continuous-batching engine with heuristic plans, and checks:
 
 * every quantized linear's plan is the backend the registry picks on a
-  TPU (the fused ``msgemm_pallas`` kernel), running compiled;
+  TPU (``msgemm_mxu``, the stored codes on the MXU), running compiled;
 * no step retry, replan, quarantined backend or NaN event, and every
   request finished ``ok``;
 * greedy tokens equal the static ``generate`` path's (``serve --check``);

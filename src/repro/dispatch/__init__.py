@@ -132,6 +132,11 @@ def execute(params: dict, x, cfg, *, in_dim: int | None = None,
             "dispatch_epilogue_total",
             help="non-identity epilogues by fused/unfused execution",
             fused="true" if fuse else "false").inc()
+    if spec.mode == "msgemm":
+        obs.registry().counter(
+            "dispatch_backend_total",
+            help="msgemm-mode linears by the backend that runs them",
+            backend=be.name).inc()
     sharded = p.shard is not None and p.shard.is_sharded
     if sharded or not be.partitionable:
         from repro.distributed.sharding import active_mesh

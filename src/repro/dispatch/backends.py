@@ -3,7 +3,8 @@
 Each ``run`` body is the corresponding branch that used to live inline
 in ``core.linear.apply`` (dense, jnp msGeMM, fused Pallas msGeMM,
 int4 dequant) — moved behind the registry so numerics are unchanged —
-plus ``int4_pallas``, the blocked dequant+MXU Pallas kernel.
+plus ``int4_pallas``, the blocked dequant+MXU Pallas kernel, and
+``msgemm_mxu``, which contracts the stored msGeMM codes on the MXU.
 
 ``run`` takes optional ``epilogue``/``bias``/``residual`` kwargs:
 ``dispatch.execute`` only passes them when the backend's ``epilogue_ok``
@@ -12,11 +13,11 @@ the plan allows fusion) — the Pallas kernels then execute the tail
 inside their final VMEM writeback; every other backend never sees an
 epilogue and ``execute`` applies it unfused after ``run``.
 
-Priorities encode today's defaults so registry auto-selection matches
-the old hardcoded if/elif chain: ``msgemm_jnp`` outranks the fused
-Pallas kernel everywhere except real TPU (where the fused kernel is the
-point of the paper), and ``int4_jnp`` outranks ``int4_pallas`` (the jnp
-dequant path is what `mode='int4_dequant'` always did).
+Priorities: on a TPU ``msgemm_mxu`` runs uniform-code msGeMM weights
+and the LUT kernel ``msgemm_pallas`` the learned codebooks; everywhere
+else both run only in interpret mode, below ``msgemm_jnp``.
+``int4_jnp`` outranks ``int4_pallas`` (the jnp dequant path is what
+`mode='int4_dequant'` always did).
 """
 
 from __future__ import annotations
@@ -48,7 +49,7 @@ def _out_dtype(epilogue, x):
 
 
 def _pallas_epilogue_ok(epilogue) -> bool:
-    """Both Pallas kernels fuse the full epilogue envelope: any
+    """The Pallas kernels fuse the full epilogue envelope: any
     activation in core.epilogue.ACTIVATIONS, bias, residual, out cast."""
     return True
 
@@ -126,6 +127,20 @@ def run_msgemm_pallas(spec, plan, params, x, *, k, precision=None,
     return y.T.reshape(*batch, -1).astype(_out_dtype(epilogue, x))
 
 
+def run_msgemm_mxu(spec, plan, params, x, *, k, precision=None,
+                   epilogue=None, bias=None, residual=None):
+    from repro.kernels import ops as kops
+
+    m = params["scales"].shape[0]
+    batch = x.shape[:-1]
+    y = kops.msgemm_mxu(
+        params["idx"], params["scales"], x.reshape(-1, k),
+        spec.resolve_d(k, m), scale_block=spec.scale_block,
+        interpret=plan.interpret, epilogue=epilogue, bias=bias,
+        residual=None if residual is None else residual.reshape(-1, m))
+    return y.reshape(*batch, m)
+
+
 def run_dense_fallback(spec, plan, params, x, *, k, precision=None,
                        epilogue=None, bias=None, residual=None):
     """Dequantize to dense and matmul — numerically the quantization
@@ -160,9 +175,10 @@ register_backend(
     tunable=("consume_chunk",),
     description="produce/consume msGeMM in lowerable jnp (scan consume)")
 
-# On real TPU the fused kernel IS the paper's contribution — it outranks
-# the scan formulation there; everywhere else it only runs in interpret
-# mode, so auto-selection demotes it below msgemm_jnp.
+# The paper's LUT kernel: on a TPU it outranks the scan formulation (and
+# serves the learned codebooks msgemm_mxu leaves to it); everywhere else
+# it only runs in interpret mode, so auto-selection demotes it below
+# msgemm_jnp.
 register_backend(
     "msgemm_pallas", modes=("msgemm",), run=run_msgemm_pallas,
     priority=lambda dev: 60 if dev == "tpu" else 40,
@@ -170,6 +186,17 @@ register_backend(
     epilogue_ok=_pallas_epilogue_ok, partitionable=False,
     description="fused VMEM-tiled produce+consume Pallas kernel "
                 "(amortized produce, VMEM acc stripe, fused epilogue)")
+
+# The stored codes contracted on the MXU with factored f32 scales: on a
+# TPU it outranks the LUT kernel, whose 16^d-entry gather runs on the
+# vector unit; elsewhere it runs only in interpret mode, below both.
+register_backend(
+    "msgemm_mxu", modes=("msgemm",), run=run_msgemm_mxu,
+    priority=lambda dev: 70 if dev == "tpu" else 30,
+    storages=("packed_idx",), codebooks=("none",),
+    epilogue_ok=_pallas_epilogue_ok, partitionable=False,
+    description="stored int4 codes x activations on the MXU, per-block "
+                "f32 scales, fused epilogue (kernels/msgemm_mxu)")
 
 register_backend(
     "int4_jnp", modes=("int4_dequant",), run=run_int4_jnp, priority=50,

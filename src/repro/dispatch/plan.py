@@ -278,6 +278,8 @@ def heuristic_plan(spec: QuantSpec, d: int, m: int, k: int, batch: int,
                         acc_in_vmem=ops.acc_stripe_fits(m, tm, tb),
                         acc_dtype=policy.acc_dtype,
                         interpret=policy.interpret)
+    if backend == "msgemm_mxu":  # the kernel sizes its own tiles
+        return ExecPlan(backend=backend, interpret=policy.interpret)
     if backend == "int4_pallas":
         tm, tk, tb = ops.int4_tiles(m, k, batch, spec.scale_block)
         return ExecPlan(backend=backend, tm=tm, tj=tk, tb=tb,

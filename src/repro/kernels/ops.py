@@ -29,6 +29,7 @@ from repro.core import packing
 from repro.core.epilogue import Epilogue
 from repro.kernels import int4_matmul as _i4
 from repro.kernels import msgemm as _ms
+from repro.kernels import msgemm_mxu as _mx
 from repro.kernels.mode import resolve_interpret
 
 VMEM_BUDGET = 8 * 1024 * 1024  # conservative per-step LUT budget (bytes)
@@ -216,6 +217,20 @@ def msgemm(codes: jnp.ndarray, x: jnp.ndarray, d: int, *,
     if not fuse:
         y = _epilogue_cols(y, ep, bias, residual)
     return y[:, 0] if squeeze else y
+
+
+def msgemm_mxu(idx: jnp.ndarray, scales: jnp.ndarray, x: jnp.ndarray,
+               d: int, *, scale_block: int, interpret: bool | None = None,
+               epilogue: Epilogue | None = None,
+               bias: jnp.ndarray | None = None,
+               residual: jnp.ndarray | None = None) -> jnp.ndarray:
+    """y (b, m) = epilogue(x (b, k) @ dequant(idx (m, k/d), scales).T) on
+    the MXU (kernels/msgemm_mxu.py), in row layout: ``bias`` (m,),
+    ``residual`` (b, m).  ``idx`` and ``scales`` are the stored arrays
+    and reach the kernel untouched; the kernel sizes its own tiles."""
+    return _mx.msgemm_mxu(idx, scales, x, bias, residual, d=d,
+                          scale_block=scale_block, interpret=interpret,
+                          epilogue=epilogue)
 
 
 def int4_matmul(u8: jnp.ndarray, scales: jnp.ndarray, x: jnp.ndarray, *,

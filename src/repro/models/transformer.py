@@ -305,8 +305,12 @@ def logits_from_hidden(params, cfg: ModelConfig, x) -> jnp.ndarray:
         logits = jnp.einsum("bsd,vd->bsv", x.astype(jnp.float32),
                             params["embedding"].astype(jnp.float32))
     else:
+        # float32 out of the kernel, as the tied head computes them: a
+        # bf16 logit rounds by up to 1/16 at magnitude 8-16, which can
+        # flip a greedy choice between near-tied tokens
         logits = common.linear_apply(params["lm_head"], x, cfg.quant,
-                                     in_dim=cfg.d_model, tag="lm_head").astype(jnp.float32)
+                                     in_dim=cfg.d_model, tag="lm_head",
+                                     out_dtype="float32")
     logits = common.softcap(logits, cfg.final_logit_softcap)
     return constrain(logits, "batch", "seq", "vocab")
 

@@ -157,13 +157,43 @@ def test_registry_backends_and_selection():
         assert expected in names
     assert dispatch.select_backend(DENSE, 0, "cpu").name == "dense"
     assert dispatch.select_backend(MS, 3, "cpu").name == "msgemm_jnp"
-    assert dispatch.select_backend(MS, 3, "tpu").name == "msgemm_pallas"
+    # on a TPU uniform codes run on the MXU; the LUT kernel keeps the
+    # learned codebooks, which msgemm_mxu does not declare
+    assert dispatch.select_backend(MS, 3, "tpu").name == "msgemm_mxu"
+    ms_cb = dataclasses.replace(MS, codebook="learned")
+    assert dispatch.select_backend(ms_cb, 3, "tpu").name == "msgemm_pallas"
+    assert dispatch.select_backend(ms_cb, 3, "cpu").name == "msgemm_jnp"
     i4 = QuantSpec(mode="int4_dequant", d=3, scale_block=12)
     assert dispatch.select_backend(i4, 3, "cpu").name == "int4_jnp"
     # capability: int4_pallas dequantizes the uniform grid only
     i4cb = dataclasses.replace(i4, codebook="learned")
     avail = [b.name for b in dispatch.available_backends(i4cb, 3, "cpu")]
     assert "int4_pallas" not in avail and "int4_jnp" in avail
+
+
+def test_backend_counter_counts_msgemm_linears_per_call_site(lin):
+    """dispatch_backend_total{backend} counts each traced msgemm-mode
+    linear by the backend it ran on; dense linears are not counted."""
+    from repro import obs
+
+    p_dense, x = lin
+    p = linear.from_dense(p_dense["w"], MS)
+
+    def count(name):
+        return obs.registry().value("counter", "dispatch_backend_total",
+                                    backend=name) or 0
+
+    before = {n: count(n) for n in ("msgemm_jnp", "msgemm_mxu", "dense")}
+    linear.apply(p, x, MS, in_dim=24)
+    linear.apply(p, x, MS, in_dim=24,
+                 policy=ExecPolicy(backend="msgemm_mxu", interpret=True))
+    f = jax.jit(lambda p, x: linear.apply(p, x, MS, in_dim=24))
+    f(p, x)
+    f(p, x)  # a cached executable traces nothing: no count
+    linear.apply(p_dense, x, DENSE)
+    assert count("msgemm_jnp") == before["msgemm_jnp"] + 2
+    assert count("msgemm_mxu") == before["msgemm_mxu"] + 1
+    assert count("dense") == before["dense"]
 
 
 def test_register_backend_duplicate_and_priority():
@@ -383,7 +413,8 @@ def test_using_policy_scoped(lin):
 
 
 # ----------------------------------------------------- backend parity
-@pytest.mark.parametrize("backend", ["msgemm_jnp", "msgemm_pallas"])
+@pytest.mark.parametrize("backend",
+                         ["msgemm_jnp", "msgemm_pallas", "msgemm_mxu"])
 def test_msgemm_backends_match_dequant(lin, backend):
     p_dense, x = lin
     p = linear.from_dense(p_dense["w"], MS)
@@ -441,7 +472,8 @@ def test_engine_token_identity_across_backends(small_model):
     _, base = _engine_tokens(p, c)
     _, jnp_toks = _engine_tokens(p, c, backend="msgemm_jnp")
     _, pallas_toks = _engine_tokens(p, c, backend="msgemm_pallas")
-    assert base == jnp_toks == pallas_toks
+    _, mxu_toks = _engine_tokens(p, c, backend="msgemm_mxu")
+    assert base == jnp_toks == pallas_toks == mxu_toks
 
 
 def test_engine_autotune_resolves_plans_at_build(small_model, tmp_path):
@@ -472,12 +504,14 @@ def test_epilogue_capability_predicates():
 
     ep = Epilogue(act="gelu", residual=True)
     assert registry.get_backend("msgemm_pallas").epilogue_ok(ep)
+    assert registry.get_backend("msgemm_mxu").epilogue_ok(ep)
     assert registry.get_backend("int4_pallas").epilogue_ok(ep)
     assert not registry.get_backend("msgemm_jnp").epilogue_ok(ep)
     assert not registry.get_backend("dense").epilogue_ok(ep)
 
 
-@pytest.mark.parametrize("backend", ["msgemm_jnp", "msgemm_pallas"])
+@pytest.mark.parametrize("backend",
+                         ["msgemm_jnp", "msgemm_pallas", "msgemm_mxu"])
 def test_epilogue_through_linear_apply(lin, backend):
     """linear.apply(epilogue=...) equals separate elementwise ops for
     both a fusing backend (Pallas) and the unfused fallback (jnp)."""
@@ -579,6 +613,26 @@ def test_decode_plan_small_batch_tb():
     hp = dispatch.heuristic_plan(MS, 3, 2048, 768, 4, "msgemm_pallas",
                                  ExecPolicy())
     assert hp.tb == 8 and hp.tm == 512
+
+
+def test_untied_head_logits_are_float32_from_the_kernel():
+    """A bf16 model's untied LM head emits float32 logits, as the tied
+    head does: no bf16 rounding of the logits before the greedy pick."""
+    from repro.models import transformer as T
+    from repro.models.config import ModelConfig
+
+    spec = QuantSpec(mode="msgemm", d=3, scale_block=36)
+    cfg = ModelConfig(num_layers=1, d_model=72, num_heads=4, num_kv_heads=2,
+                      d_ff=144, vocab_size=256, max_seq_len=16,
+                      dtype="bfloat16", quant=spec)
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 3, 72), jnp.bfloat16)
+    for backend in ("msgemm_pallas", "msgemm_mxu"):  # fusing kernels
+        with dispatch.using_policy(ExecPolicy(backend=backend)):
+            lg = T.logits_from_hidden(params, cfg, x)
+        assert lg.dtype == jnp.float32
+        rounded = lg.astype(jnp.bfloat16).astype(jnp.float32)
+        assert not bool(jnp.all(lg == rounded)), backend
 
 
 def test_model_epilogue_fusion_matches_unfused(small_model):

@@ -16,8 +16,10 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import packing, scales as scales_mod
+from repro import dispatch
+from repro.core import linear, packing, scales as scales_mod
 from repro.core.epilogue import Epilogue
+from repro.core.spec import QuantSpec
 from repro.kernels import ops, ref
 from repro.kernels.msgemm import msgemm_pallas
 from repro.kernels.int4_matmul import int4_matmul_pallas
@@ -383,6 +385,138 @@ def test_kernel_activation_dtypes(dtype):
                           d=3, scale_block=12)
     tol = 1e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+
+# ------------------------------------------------------ msgemm on the MXU
+MXU_SPEC = QuantSpec(mode="msgemm", d=3, scale_block=36)
+
+# (rows, m, k, activation dtype, epilogue); k = 100 is no multiple of 3
+# or 36, m = 200 and 130 no multiple of 128, k = 4700 spans two k tiles
+MXU_CASES = [
+    (1, 200, 100, jnp.float32, Epilogue()),
+    (8, 200, 100, jnp.float32, Epilogue()),
+    (16, 200, 100, jnp.float32, Epilogue()),
+    (8, 130, 4700, jnp.float32, Epilogue()),
+    (16, 130, 4700, jnp.bfloat16, Epilogue()),
+    (8, 256, 72, jnp.bfloat16, Epilogue()),
+    (8, 256, 4700, jnp.float32, Epilogue()),  # idx read column-major
+] + [(8, 200, 100, jnp.float32, ep) for ep in EPILOGUES[1:]] + [
+    (16, 200, 100, jnp.bfloat16,
+     Epilogue(act="gelu", residual=True, out_dtype="bfloat16")),
+]
+
+
+@pytest.mark.parametrize("rows,m,k,dtype,ep", MXU_CASES, ids=lambda v: (
+    v.__name__ if isinstance(v, type) else
+    f"{v.act}{'+b' if v.bias else ''}{'+r' if v.residual else ''}"
+    f"{'+' + v.out_dtype if v.out_dtype else ''}"
+    if isinstance(v, Epilogue) else str(v)))
+def test_msgemm_mxu_matches_lut_and_dense(rows, m, k, dtype, ep):
+    """msgemm_mxu through dispatch.execute equals msgemm_pallas (same
+    sums, f32 order aside) and the dequantized dense product, for every
+    row count, ragged m and k, activation dtype and epilogue."""
+    rng = np.random.default_rng(rows * 1000 + m + k)
+    p = linear.from_dense(
+        jnp.asarray(rng.standard_normal((m, k)), jnp.float32), MXU_SPEC)
+    x = jnp.asarray(rng.standard_normal((rows, k)), jnp.float32)
+    bias = (jnp.asarray(rng.standard_normal(m), jnp.float32)
+            if ep.bias else None)
+    res = (jnp.asarray(rng.standard_normal((rows, m)), dtype)
+           if ep.residual else None)
+
+    def run(name, x):
+        return dispatch.execute(
+            p, x, MXU_SPEC, in_dim=k, epilogue=ep, bias=bias, residual=res,
+            plan_override=dispatch.ExecPlan(backend=name))
+
+    got = run("msgemm_mxu", x.astype(dtype))
+    lut = run("msgemm_pallas", x.astype(dtype))
+    dense = run("dense_fallback", x.astype(dtype).astype(jnp.float32))
+    assert got.shape == (rows, m) and got.dtype == lut.dtype
+    f32 = lambda a: np.asarray(a, np.float32)
+    scale = float(np.max(np.abs(f32(dense))))
+    tol = 2e-6 if got.dtype == jnp.float32 else 2**-7
+    np.testing.assert_allclose(f32(got), f32(lut), rtol=tol,
+                               atol=tol * scale)
+    np.testing.assert_allclose(f32(got), f32(dense), rtol=10 * tol,
+                               atol=10 * tol * scale)
+
+
+@pytest.mark.parametrize("d,scale_block,m,k,b", BITEXACT_SHAPES + [
+    (3, 36, 130, 4700, 9),  # two k tiles, the second ragged
+    (3, 36, 256, 4700, 9),  # the same, idx read column-major
+])
+def test_msgemm_mxu_bitexact_sweep(d, scale_block, m, k, b):
+    """On exactly representable inputs the MXU kernel equals the consume
+    oracle bit for bit: a wrong code plane, scale block or tile edge
+    changes the integer result."""
+    rng = np.random.default_rng(d * 103 + m + k + b)
+    codes, x, sc = _mk_exact(rng, m, k, b, scale_block)
+    idx = packing.pack_indices(codes, d)
+    got = ops.msgemm_mxu(idx, sc, x.T, d, scale_block=scale_block)
+    want = ref.msgemm_ref(idx, x, sc, d=d, scale_block=scale_block)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want).T)
+
+
+@pytest.mark.parametrize("cap", [1, 2])
+def test_msgemm_mxu_splits_m_into_groups(monkeypatch, cap):
+    """Where the VMEM output stripe would outgrow ``STRIPE_BUDGET`` (a
+    vocab-sized head prefilled at 128 rows), m is split into groups of
+    ``cap`` tiles, the last of which may repeat a tile past m: still bit
+    for bit the oracle plus the fused bias and residual."""
+    from repro.kernels import msgemm_mxu as mx
+
+    rows, m, k = 20, 1100 + cap, 100  # three m tiles of 512; own shapes
+    stripe = 4 + 2 * 4 + 2 * 4        # f32 acc, out and residual blocks
+    monkeypatch.setattr(mx, "STRIPE_BUDGET", 24 * 512 * stripe * cap)
+    assert mx.m_groups(3, 512, 24, stripe) == ((3, 1), (2, 2))[cap - 1]
+    rng = np.random.default_rng(cap)
+    codes, x, sc = _mk_exact(rng, m, k, rows, 36)
+    bias = jnp.asarray(rng.integers(-4, 5, size=m), jnp.float32)
+    res = jnp.asarray(rng.integers(-4, 5, size=(rows, m)), jnp.float32)
+    idx = packing.pack_indices(codes, 3)
+    got = ops.msgemm_mxu(idx, sc, x.T, 3, scale_block=36, bias=bias,
+                         residual=res,
+                         epilogue=Epilogue(bias=True, residual=True))
+    want = ref.msgemm_ref(idx, x, sc, d=3, scale_block=36).T + bias + res
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("m,k,idx_t", [(200, 600, False), (200, 100, True)])
+def test_msgemm_mxu_reads_stored_weights_untouched(m, k, idx_t):
+    """The linear's idx and scales reach the kernel as stored: in the
+    traced step nothing but the kernel consumes them (no unpack, repack
+    or pad of a weight-sized array), at most through a transpose where
+    the chip stores the array column-major, which is a bitcast there
+    (tests/test_chip_compile.py checks the compiled step)."""
+    from repro.kernels.msgemm_mxu import stored_transposed
+
+    assert stored_transposed(m, -(-k // 3)) == idx_t
+    p = linear.from_dense(jnp.ones((m, k)), MXU_SPEC)
+    pol = dispatch.ExecPolicy(backend="msgemm_mxu")
+    closed = jax.make_jaxpr(lambda p, x: dispatch.execute(
+        p, x, MXU_SPEC, in_dim=k, policy=pol))(p, jnp.ones((8, k)))
+
+    def consumers(jaxpr, var):
+        out = []
+        for eqn in jaxpr.eqns:
+            for i, v in enumerate(eqn.invars):
+                if v is not var:
+                    continue
+                if eqn.primitive.name == "jit":  # follow into the call
+                    inner = eqn.params["jaxpr"].jaxpr
+                    out += consumers(inner, inner.invars[i])
+                elif eqn.primitive.name == "transpose":
+                    out += ["transpose>" + c for c in
+                            consumers(jaxpr, eqn.outvars[0])]
+                else:
+                    out.append(eqn.primitive.name)
+        return out
+
+    idx_var, scales_var, _ = closed.jaxpr.invars  # sorted dict leaves, x
+    assert consumers(closed.jaxpr, idx_var) == [
+        "transpose>pallas_call" if idx_t else "pallas_call"]
+    assert consumers(closed.jaxpr, scales_var) == ["transpose>pallas_call"]
 
 
 # ------------------------------------------------------- flash attention
