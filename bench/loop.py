@@ -41,6 +41,8 @@ class Step:
     rows: sequences in a decode step, prompt tokens in a prefill chunk.
     head_rows: rows whose next-token logits are used.
     ctx: sum over the rows of the positions each row attends to.
+    ctx_local: the same sum with each row's positions capped at the
+    configuration's sliding window (``ctx`` where it has none).
     """
 
     t0: float
@@ -50,25 +52,49 @@ class Step:
     head_rows: int
     ctx: int
     tokens: int
+    ctx_local: int
+
+
+def _attended(positions, window: int) -> tuple[int, int]:
+    """(sum, window-capped sum) of the positions each row attends to."""
+    full = sum(positions)
+    return full, (sum(min(p, window) for p in positions) if window
+                  else full)
 
 
 class Loop:
-    def __init__(self, engine, traffic, *, clock=time.perf_counter):
+    def __init__(self, engine, traffic, *, window: int = 0,
+                 clock=time.perf_counter):
         from repro.serving import Phase, Request
 
         self._Request, self._Phase = Request, Phase
         self.engine = engine
         self.traffic = traffic
+        self.attn_window = window
         self.clock = clock
         self.reqs: dict[int, Req] = {}
         self.emitted = 0
         self.compiles = 0
+        self.jax_events: list[tuple[float, str, float]] = []
         engine.on_token = self._on_token
         jax.monitoring.register_event_duration_secs_listener(self._on_event)
 
     def _on_event(self, event, secs, **kw):
+        self.jax_events.append((self.clock(), event, secs))
         if event == "/jax/core/compile/backend_compile_duration":
             self.compiles += 1
+
+    def events_in(self, t0: float, t1: float) -> str:
+        """JAX's timed events (tracing, compiling, compile-cache loads)
+        that ended in [t0, t1], by name: count and seconds."""
+        got: dict[str, list] = {}
+        for t, name, secs in self.jax_events:
+            if t0 <= t <= t1:
+                n = got.setdefault(name.rsplit("/", 1)[-1], [0, 0.0])
+                n[0] += 1
+                n[1] += secs
+        return ", ".join(f"{k} x{n} {s:.4f} s"
+                         for k, (n, s) in sorted(got.items())) or "none"
 
     def _on_token(self, rid, token, text):
         r = self.reqs[rid]
@@ -93,8 +119,9 @@ class Loop:
         sched = eng.scheduler
         pos0 = [(s, s.prefill_pos) for s in (*sched.running, *sched.waiting)]
         p0, d0, n0 = eng.num_prefill_steps, eng.num_decode_steps, self.emitted
-        dec_ctx = sum(s.num_tokens for s in sched.running
-                      if s.phase is self._Phase.DECODE)
+        dec_ctx = _attended([s.num_tokens for s in sched.running
+                             if s.phase is self._Phase.DECODE],
+                            self.attn_window)
         t0 = self.clock()
         with jax.profiler.TraceAnnotation("engine.step"):
             done = eng.step()
@@ -109,13 +136,16 @@ class Loop:
         if eng.num_prefill_steps > p0:
             for s, old in pos0:
                 if s.prefill_pos > old:
+                    full, local = _attended(
+                        range(old + 1, s.prefill_pos + 1),
+                        self.attn_window)
                     return Step(t0, t1, "prefill", s.prefill_pos - old,
                                 int(s.phase is not self._Phase.PREFILL),
-                                sum(range(old + 1, s.prefill_pos + 1)),
-                                tokens)
+                                full, tokens, local)
         if eng.num_decode_steps > d0:
-            return Step(t0, t1, "decode", tokens, tokens, dec_ctx, tokens)
-        return Step(t0, t1, "idle", 0, 0, 0, tokens)
+            return Step(t0, t1, "decode", tokens, tokens, dec_ctx[0],
+                        tokens, dec_ctx[1])
+        return Step(t0, t1, "idle", 0, 0, 0, tokens, 0)
 
     # ---------------------------------------------------------- phases
     def warm(self) -> None:
@@ -143,6 +173,13 @@ class Loop:
             while not steps or steps[-1].t1 - w0 < seconds:
                 steps.append(self.step())
         return w0, steps[-1].t1, steps, self.compiles - c0
+
+
+def longest(steps: list, w0: float, n: int = 5) -> str:
+    """The ``n`` longest steps: kind, seconds and start in the window."""
+    top = sorted(steps, key=lambda s: s.t0 - s.t1)[:n]
+    return ", ".join(f"{s.kind} {s.t1 - s.t0:.4f} s at +{s.t0 - w0:.2f} s"
+                     for s in top)
 
 
 class GcPauses:

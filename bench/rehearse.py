@@ -3,11 +3,15 @@
     JAX_PLATFORMS=cpu python3 bench/rehearse.py [--skip-compile]
 
 1. Each cell of ``BENCHMARK.json`` runs end to end on the CPU at a tiny
-   size of its own configuration (2 layers, hidden 72), with the msGeMM
-   Pallas kernel in interpret mode, once untraced and once traced.
-2. Each configuration's prefill and decode step is compiled at its full
-   size for a described TPU v5e, and the compiler's memory analysis is
-   printed: a tiling, VMEM or memory refusal shows here, not on the chip.
+   size of its own configuration (one period of its block pattern,
+   hidden 72), on the backend the CPU selects, once untraced and once
+   traced.
+2. Each cell's prefill and decode step is compiled at its full size for
+   a described TPU v5e, on the backend selection picks for a TPU, and
+   the compiler's memory analysis is printed: a tiling, VMEM or memory
+   refusal shows here, not on the chip.  A cell on several chips is
+   compiled over a ``model`` mesh of that many described v5e chips, with
+   the serving engine's sharding rules.
 
 A script run by hand before a chip call, not a test.
 """
@@ -17,6 +21,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import re
 import shutil
 import sys
 import tempfile
@@ -36,11 +41,14 @@ TINY_MIX = {"clients": 2, "requests_per_client": 4,
 
 
 def tiny_config(c: dict) -> dict:
-    """The same architecture at a CPU size: GQA stays GQA."""
+    """The same architecture at a CPU size: GQA stays GQA, and a block
+    pattern keeps one whole period."""
     out = dict(c, **TINY)
     out["num_key_value_heads"] = (TINY["num_attention_heads"]
                                   if c["num_key_value_heads"]
                                   == c["num_attention_heads"] else 2)
+    period = len(c["program"].get("model", {}).get("block_pattern", ()))
+    out["num_hidden_layers"] = max(period, TINY["num_hidden_layers"])
     return out
 
 
@@ -67,10 +75,7 @@ def tiny_tree(dest: Path, root: Path = ROOT) -> list[str]:
 
 def rehearse_cells() -> None:
     from bench import run
-    from repro import dispatch
 
-    # the chip's backend, in interpret mode on the CPU
-    dispatch.set_default_policy(dispatch.ExecPolicy(backend="msgemm_pallas"))
     with tempfile.TemporaryDirectory() as tmp:
         names = tiny_tree(Path(tmp))
         for name in names:
@@ -82,25 +87,30 @@ def rehearse_cells() -> None:
                       f"{json.dumps(out)[:2000]}", flush=True)
 
 
-def compile_for_v5e() -> None:
+def compile_for_v5e(root: Path = ROOT) -> None:
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from contextlib import nullcontext
+    from unittest import mock
+
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
+    from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec,
+                              SingleDeviceSharding)
 
     from bench import layout, model
     from repro import dispatch
+    from repro.dispatch import registry
+    from repro.distributed import sharding as shd
     from repro.models import transformer as T
     from repro.runtime import serve as SV
 
     jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
-    one = SingleDeviceSharding(topo.devices[0])
-    policy = dispatch.ExecPolicy(backend="msgemm_pallas", interpret=False)
-    spec = layout.Benchmark(ROOT)
+    policy = dispatch.ExecPolicy(interpret=False)
+    spec = layout.Benchmark(root)
     for w in spec.spec["workloads"]:
         cell = spec.cell(w["name"])
         cfg = model.model_config(cell.config)
@@ -108,12 +118,29 @@ def compile_for_v5e() -> None:
         bs, B = eng["block_size"], eng["max_slots"]
         W = -(-eng["max_model_len"] // bs) * bs
         nb = B * (W // bs) + 1
-        place = lambda t: jax.tree.map(  # noqa: E731
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), t)
-        params = place(jax.eval_shape(lambda k: T.init_params(k, cfg),
-                                      jax.random.PRNGKey(0)))
-        pool = place(jax.eval_shape(lambda: SV.init_paged_cache(
-            cfg, nb, bs, model.kv_dtype(cell.config))))
+        params = jax.eval_shape(lambda k: T.init_params(k, cfg),
+                                jax.random.PRNGKey(0))
+        pool = jax.eval_shape(lambda: T.init_paged_cache(
+            cfg, nb, bs, model.kv_dtype(cell.config)))
+        if cell.chips > 1:
+            mesh = Mesh(np.asarray(topo.devices[:cell.chips]), ("model",),
+                        axis_types=(AxisType.Auto,))
+            rep = NamedSharding(mesh, PartitionSpec())
+            p_sh = shd.shardings(params, mesh, model.SERVE_RULES)
+            k_sh = jax.tree.map(
+                lambda s: NamedSharding(mesh, s),
+                shd.paged_cache_specs(pool, mesh, model.SERVE_RULES),
+                is_leaf=lambda s: isinstance(s, PartitionSpec))
+            scope = lambda: shd.use(mesh, model.SERVE_RULES)  # noqa: E731
+        else:
+            rep = SingleDeviceSharding(topo.devices[0])
+            p_sh = jax.tree.map(lambda _: rep, params)
+            k_sh = jax.tree.map(lambda _: rep, pool)
+            scope = nullcontext
+        place = lambda t, sh: jax.tree.map(  # noqa: E731
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            t, sh)
+        params, pool = place(params, p_sh), place(pool, k_sh)
 
         def step(params, pool, tokens, positions, ws, vs, last):
             logits, pool = SV.paged_step(params, cfg, tokens, pool,
@@ -121,15 +148,20 @@ def compile_for_v5e() -> None:
             return jnp.argmax(logits, -1), pool
 
         for label, (nb_, nt) in (("prefill", (1, 16)), ("decode", (B, 1))):
-            ints = [jax.ShapeDtypeStruct(s, np.int32, sharding=one)
+            ints = [jax.ShapeDtypeStruct(s, np.int32, sharding=rep)
                     for s in ((nb_, nt), (nb_, nt), (nb_, nt), (nb_, W),
                               (nb_,))]
             t = time.perf_counter()
-            with dispatch.using_policy(policy):
+            # backend selection as on the chip
+            with mock.patch.object(registry, "device_kind", lambda: "tpu"), \
+                    scope(), dispatch.using_policy(policy):
                 compiled = jax.jit(step, donate_argnums=(1,)).lower(
                     params, pool, *ints).compile()
+            backends = sorted(set(re.findall(
+                r"%(msgemm_\w+?)(?:\.\d+)? = ", compiled.as_text())))
             mem = compiled.memory_analysis()
-            print(f"{cell.name} {label}: compiled for v5e in "
+            print(f"{cell.name} {label} on {cell.chips} chip(s), linear "
+                  f"kernels {backends}: compiled for v5e in "
                   f"{time.perf_counter() - t:.1f} s; arguments "
                   f"{mem.argument_size_in_bytes / 2**30:.2f} GiB, temps "
                   f"{mem.temp_size_in_bytes / 2**30:.2f} GiB, outputs "

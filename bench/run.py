@@ -113,8 +113,15 @@ def run_cell(root: Path, name: str, seed: int, seconds: float,
         raise Refused(f"a plan-cache file exists at {plan_file}")
     dispatch.set_cache_path(plan_file)
 
-    params, cfg = model.build(cell.config, seed)
+    mesh = None
+    if cell.chips > 1:
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((cell.chips,), ("model",))
+        log(f"mesh {dict(mesh.shape)}")
+    params, cfg = model.build(cell.config, seed, mesh)
     engine = Engine(params, cfg, cache_dtype=model.kv_dtype(cell.config),
+                    mesh=mesh, mesh_rules=model.SERVE_RULES,
                     **cell.engine)
     del params
     traffic = ClosedLoop(cell.mix, cfg.vocab_size, seed)
@@ -122,7 +129,7 @@ def run_cell(root: Path, name: str, seed: int, seconds: float,
         raise ValueError(f"mix {cell.traffic!r} needs {traffic.max_len} "
                          f"positions, the engine holds "
                          f"{engine.max_model_len}")
-    drive = L.Loop(engine, traffic)
+    drive = L.Loop(engine, traffic, window=work.window(cell.config))
     trace_dir = Path(tempfile.mkdtemp(prefix="bench-trace-")) if trace \
         else None
     with L.GcPauses() as pauses:
@@ -143,6 +150,8 @@ def run_cell(root: Path, name: str, seed: int, seconds: float,
     setup_s = w0 - t_start
     log(f"set-up {setup_s:.3f} s; window {w1 - w0:.3f} s, {len(steps)} "
         f"steps; compilations in the window: {compiles}")
+    log(f"JAX events in the window: {drive.events_in(w0, w1)}")
+    log(f"longest steps in the window: {L.longest(steps, w0)}")
     log(f"garbage collection in the fill: "
         f"{pauses.summary(t_fill, t_frozen)}; in the window: "
         f"{pauses.summary(w0, w1)}")

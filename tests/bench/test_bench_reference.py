@@ -1,6 +1,8 @@
 """Each plain reference (bench/reference/) against the program's own
 forward at a tiny size of the same family: greedy tokens the program
-generates in float32 are the reference's first choice at every step."""
+generates in float32 are the reference's first choice at every step.
+The dense reference is also held to the block kinds a configuration can
+ask for in ``program.model``: windows and experts."""
 
 from __future__ import annotations
 
@@ -18,6 +20,7 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 from bench import model  # noqa: E402
 from bench.rehearse import tiny_config  # noqa: E402
 from bench.reference import dense  # noqa: E402
+from variants import VARIANTS, variant  # noqa: E402
 
 CONFIGS = sorted(p.stem for p in (ROOT / "bench" / "configs").glob("*.json"))
 SEED = 2**32 + 9
@@ -45,7 +48,31 @@ def served(c, prompt, n):
 
 @pytest.mark.parametrize("name", CONFIGS)
 def test_reference_agrees_with_the_program_in_float32(name):
-    c = tiny(name, "float32")
+    agrees(tiny(name, "float32"))
+
+
+@pytest.mark.parametrize("kind", sorted(VARIANTS))
+def test_reference_agrees_with_the_program_on_block_kinds(kind):
+    # served sequences of 14 and 20 positions pass the window of 8
+    c = variant(tiny("phi3-mini", "float32"), kind)
+    agrees(c)
+    if kind == "local":  # the window is what the program runs
+        wide = variant(tiny("phi3-mini", "float32"), kind)
+        wide["program"]["model"] = dict(wide["program"]["model"],
+                                        sliding_window=64)
+        seqs = [((1, 2, 3, 4, 5), served(c, (1, 2, 3, 4, 5), 12))]
+        assert dense.readings(wide, SEED, seqs)["max_logit_gap"] > \
+            100 * F32_TOL
+
+
+def test_a_field_the_reference_does_not_model_is_an_error():
+    c = tiny("phi3-mini", "float32")
+    c["program"] = dict(c["program"], model={"embed_scale": True})
+    with pytest.raises(ValueError, match="embed_scale"):
+        dense.readings(c, SEED, [((1, 2), (3, 4))])
+
+
+def agrees(c):
     rng = np.random.default_rng(3)
     prompts = [tuple(int(t) for t in rng.integers(0, 512, n))
                for n in (5, 11)]
